@@ -22,7 +22,10 @@ from repro.core import Decoder
 from repro.data import CharTokenizer, TaskDataset
 from repro.training import load, save, train
 
-CKPT_DIR = os.environ.get("REPRO_BENCH_CKPTS", "/root/repo/.bench_ckpts")
+CKPT_DIR = os.environ.get(
+    "REPRO_BENCH_CKPTS",
+    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                 ".bench_ckpts"))
 ARCH = os.environ.get("REPRO_BENCH_ARCH", "llada-8b")
 # per-task training budgets, calibrated so the decode-order effect is
 # visible: hard tasks (carry chains, parities) train long enough to be

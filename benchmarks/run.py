@@ -171,6 +171,8 @@ def main() -> None:
                     help="comma-separated subset, e.g. table1,fig2")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (ablation_carry, ablation_eta, ablation_gamma,
                             ablation_k, fig2_consistency,
                             kernel_confidence, loop_overhead,

@@ -312,7 +312,7 @@ def check_strategy(strategy, *, batch: int = 2, prompt_len: int = 4,
         def x64_probe(k, c, x, a):
             return strat.fused_step(k, c, x, a, model_fn, cfg, dcfg, n)
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             new_x, new_c, _ = jax.eval_shape(x64_probe, key, carry0, x0,
                                              active)
             # inspect INSIDE the context: result_type canonicalizes f64

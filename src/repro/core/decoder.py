@@ -179,6 +179,12 @@ class RunnerCache:
         leaves = jax.tree.leaves(model)
         if not leaves:
             raise ValueError("params pytree has no array leaves")
+        if all(isinstance(a, jax.ShapeDtypeStruct) for a in leaves):
+            # abstract params (``lower_blocks`` from shapes alone): a
+            # params-mode runner depends only on the shapes, and there is
+            # no array to anchor the entry's lifetime to
+            return ("abstract", jax.tree.structure(model),
+                    tuple((a.shape, a.dtype) for a in leaves)), ()
         return ("params", tuple(map(id, leaves))), tuple(leaves)
 
     def get(self, key: tuple, anchors: tuple, subkey: tuple,
@@ -425,9 +431,11 @@ class Decoder:
 
     # -- runner construction (all cached cross-call) -----------------------
     def _plain_runner(self, strat: Strategy,
-                      extras: Optional[Dict[str, Any]] = None) -> Callable:
-        """Per-block fused runner with uniform signature
-        ``run(x, rng, lo, sched, steps, fwd, carry) -> 5-tuple``; ``lo``
+                      extras: Optional[Dict[str, Any]] = None
+                      ) -> Tuple[Callable, tuple]:
+        """Per-block fused runner ``(run, lead)``, called as
+        ``run(*lead, x, rng, lo, sched, steps, fwd, carry) -> 5-tuple``
+        (``lead`` is the weights and extras in params mode); ``lo``
         (block start) and ``sched`` (per-step commit widths) are traced,
         so all blocks (and all later decodes with the same weights) share
         one executable per shape."""
@@ -454,7 +462,7 @@ class Decoder:
                                        x, rng, in_block, steps, fwd, carry)
                 return run
 
-            return cache.get(self._key, self._anchor, subkey, build)
+            return cache.get(self._key, self._anchor, subkey, build), ()
 
         def build():
             @jax.jit
@@ -468,9 +476,7 @@ class Decoder:
             return run
 
         raw = self._cache.get(self._key, self._anchor, subkey, build)
-        params, ex = self._params, dict(extras or {})
-        return lambda x, rng, lo, sched, steps, fwd, carry: \
-            raw(params, ex, x, rng, lo, sched, steps, fwd, carry)
+        return raw, (self._params, dict(extras or {}))
 
     def _request_runner(self, strat: Strategy, stream: bool,
                         extras: Optional[Dict[str, Any]] = None
@@ -563,10 +569,11 @@ class Decoder:
         params, ex = self._params, dict(extras or {})
         return lambda t: raw(params, ex, t)
 
-    def _refresh_runner(self) -> Callable:
-        """Jitted cache capture ``refresh(canvas) -> DecodeState`` — the
-        prefill and block-boundary refresh op of the cached path (one
-        full forward over the canvas, LM head skipped).  Strategy- and
+    def _refresh_runner(self) -> Tuple[Callable, tuple]:
+        """Jitted cache capture ``(refresh, lead)``, called as
+        ``refresh(*lead, canvas) -> DecodeState`` — the prefill and
+        block-boundary refresh op of the cached path (one full forward
+        over the canvas, LM head skipped).  Strategy- and
         dcfg-independent: every policy and strategy on the same weights
         shares one compilation per canvas shape."""
         cfg, cache = self.cfg, self._cache
@@ -581,8 +588,7 @@ class Decoder:
             return refresh
 
         raw = cache.get(self._key, self._anchor, ("refresh", cfg), build)
-        params = self._params
-        return lambda canvas: raw(params, canvas)
+        return raw, (self._params,)
 
     def _cached_forward_fn(self) -> Callable:
         """Jitted windowed forward ``(x_win, win_lo, state) -> logits``
@@ -603,11 +609,12 @@ class Decoder:
         params = self._params
         return lambda w, win_lo, st: raw(params, w, win_lo, st)
 
-    def _cached_block_runner(self, strat: Strategy) -> Callable:
-        """Per-block fused runner for the cached path: signature
-        ``run(x, rng, lo, sched, steps, fwd, carry, state)`` over the FULL
-        canvas — window slicing happens inside the trace
-        (``drive_cached_block``), with ``lo`` traced, so one executable
+    def _cached_block_runner(self, strat: Strategy
+                             ) -> Tuple[Callable, tuple]:
+        """Per-block fused runner ``(run, lead)`` for the cached path,
+        called as ``run(*lead, x, rng, lo, sched, steps, fwd, carry,
+        state)`` over the FULL canvas — window slicing happens inside the
+        trace (``drive_cached_block``), with ``lo`` traced, so one executable
         per strategy × shape × policy serves every block of every
         request.  ``state`` is the traced fixed-shape cache from
         ``_refresh_runner`` (never a baked const — ANA103)."""
@@ -625,9 +632,7 @@ class Decoder:
             return run
 
         raw = cache.get(self._key, self._anchor, subkey, build)
-        params = self._params
-        return lambda x, rng, lo, sched, steps, fwd, carry, state: \
-            raw(params, x, rng, lo, sched, steps, fwd, carry, state)
+        return raw, (self._params,)
 
     def _cached_request_runner(self, strat: Strategy, stream: bool
                                ) -> Tuple[Callable, Optional[dict]]:
@@ -803,6 +808,62 @@ class Decoder:
         geometry = self._geometry()
         return self._blocks_gen(strat, rng, prompt, geometry, extras)
 
+    def lower_blocks(self, batch: int, prompt_len: int, **extras
+                     ) -> Dict[str, jax.stages.Lowered]:
+        """Lower, without running, the per-block programs that
+        ``generate_blocks`` dispatches for a ``(batch, prompt_len)``
+        prompt — what the server runs: ``"block"``, plus ``"refresh"``
+        (the cache capture) under a cached policy.  ``compile()`` on each
+        gives its ``memory_analysis()`` and text ahead of the first
+        request.  The params (and ``extras``) may be
+        ``jax.ShapeDtypeStruct``s: lowering needs only their shapes."""
+        self._check_extras(extras)
+        dcfg = self.dcfg
+        if self._params is None:
+            raise ValueError("lower_blocks needs a params-mode Decoder")
+        strat = resolve_strategy(dcfg.strategy)
+        if dcfg.trace:
+            strat = tracing(strat)
+        if not (dcfg.fused_loop and strat.supports_fused):
+            raise ValueError(
+                f"lower_blocks lowers the fused block runner, but "
+                f"{strat.name!r} with fused_loop={dcfg.fused_loop} "
+                f"decodes on the host step loop")
+        cached = dcfg.cache_policy != "none"
+        if cached:
+            self._check_cached(extras)
+        gen, _, _, sched = self._geometry()
+        prompt = jax.ShapeDtypeStruct((batch, prompt_len), jnp.int32)
+        x, steps, fwd, carry = jax.eval_shape(
+            lambda p: self._start(strat, p, gen), prompt)
+        rng = jax.eval_shape(jax.random.PRNGKey, 0)
+        lowered, state = {}, None
+        if cached:
+            refresh, rlead = self._refresh_runner()
+            lowered["refresh"] = refresh.lower(*rlead, x)
+            state = jax.eval_shape(refresh, *rlead, x)
+        run, lead = self._cached_block_runner(strat) if cached \
+            else self._plain_runner(strat, extras)
+        args = jax.eval_shape(self._block_args, x, rng, prompt_len,
+                              sched[0], steps, fwd, carry, state)
+        lowered["block"] = run.lower(*lead, *args)
+        return lowered
+
+    def _start(self, strat: Strategy, prompt, gen: int):
+        """A decode's first ``(x, steps, fwd, carry)``: the masked canvas,
+        the zeroed step and forward counters, the strategy's carry."""
+        b, lp = prompt.shape
+        return (fully_masked(self.cfg, prompt, gen),
+                jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32),
+                strat.init_carry_shaped(self.cfg, self.dcfg, b, lp + gen))
+
+    @staticmethod
+    def _block_args(x, rng, lo, sched_row, steps, fwd, carry, state):
+        """One fused block dispatch's arguments after the runner's lead;
+        ``state`` (the KV cache) is passed only on a cached policy."""
+        return (x, rng, jnp.int32(lo), jnp.asarray(sched_row), steps, fwd,
+                carry) + (() if state is None else (state,))
+
     def _blocks_gen(self, strat: Strategy, rng, prompt, geometry, extras):
         cfg, dcfg = self.cfg, self.dcfg
         cached = dcfg.cache_policy != "none"
@@ -811,25 +872,24 @@ class Decoder:
         b, lp = prompt.shape
         gen, bs, num_blocks, sched = geometry
         total = lp + gen
-        x = fully_masked(cfg, prompt, gen)
-        carry = strat.init_carry_shaped(cfg, dcfg, b, total)
+        x, steps, fwd, carry = self._start(strat, prompt, gen)
         stats = SampleStats(tokens_generated=b * gen)
         t0 = time.perf_counter()
         # cached path: prefill captures the fixed-shape cache (= block 0's
         # refresh); later refreshes run from host at block boundaries.
         # Each capture is one full forward, accounted host-side so all
         # three drivers report the same forward_equivalents.
-        refresh = self._refresh_runner() if cached else None
+        refresh, rlead = self._refresh_runner() if cached else (None, ())
         hook = self.on_cache_refresh
 
         def timed_refresh(canvas, blk):
             if hook is None:
-                return refresh(canvas)
+                return refresh(*rlead, canvas)
             # hook installed = serving-layer tracing: the extra sync is
             # paid only then, and the blockwise caller syncs per block
             # anyway (it materializes each block's tokens on host)
             t0r = time.perf_counter()
-            st = refresh(canvas)
+            st = refresh(*rlead, canvas)
             jax.block_until_ready(st)
             hook(blk, t0r, time.perf_counter())
             return st
@@ -838,23 +898,15 @@ class Decoder:
         refresh_fwd = 1.0 if cached else 0.0
         fused = dcfg.fused_loop and strat.supports_fused
         if fused:
-            run = self._cached_block_runner(strat) if cached \
+            run, lead = self._cached_block_runner(strat) if cached \
                 else self._plain_runner(strat, extras)
-            steps = jnp.zeros((), jnp.int32)
-            fwd = jnp.zeros((), jnp.float32)
             for blk in range(num_blocks):
                 lo = lp + blk * bs
                 if cached and blk > 0 and dcfg.cache_refresh == "block":
                     state = timed_refresh(x, blk)
                     refresh_fwd += 1.0
-                if cached:
-                    x, rng, steps, fwd, carry = run(
-                        x, rng, jnp.int32(lo), jnp.asarray(sched[blk]),
-                        steps, fwd, carry, state)
-                else:
-                    x, rng, steps, fwd, carry = run(
-                        x, rng, jnp.int32(lo), jnp.asarray(sched[blk]),
-                        steps, fwd, carry)
+                x, rng, steps, fwd, carry = run(*lead, *self._block_args(
+                    x, rng, lo, sched[blk], steps, fwd, carry, state))
                 yield BlockEvent(blk, lo, lo + bs, x)
             # one sync for the whole decode: canvas + both stats counters
             x.block_until_ready()

@@ -2,9 +2,7 @@
 
 ``confidence``      — fused streaming (argmax / max-prob / margin / entropy)
 ``flash_attention`` — bidirectional flash attention + sliding-window band
-``ops``             — jit'd public wrappers with jnp fallback dispatch
 ``ref``             — pure-jnp oracles (the allclose ground truth)
-"""
-from repro.kernels.ops import attention, score_logits_fused, use_pallas
 
-__all__ = ["attention", "score_logits_fused", "use_pallas"]
+Decode callers choose the kernel through ``core.confidence.pallas_enabled``.
+"""

@@ -24,6 +24,10 @@ Grid: (row_tiles, vocab_tiles) with the vocab axis innermost; accumulators
 live in VMEM scratch and the outputs are written by the last vocab tile.
 Block shapes are MXU/VPU aligned: (ROWS=8, VTILE=512) float32 ⇒ 16 KiB per
 block, comfortably inside the ~16 MiB VMEM budget with double buffering.
+The four outputs and the five accumulators are (ROWS, 1) columns, not
+(ROWS,) vectors: Pallas TPU accepts a rank-1 block only when it spans the
+whole array or a multiple of 128 lanes, and Mosaic refuses the 1-D int32
+layout, while a 2-D block whose last dim equals the array's is legal.
 """
 from __future__ import annotations
 
@@ -58,13 +62,15 @@ def _confidence_kernel(logits_ref, argmax_ref, maxp_ref, margin_ref,
     lane = vj * VTILE + jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
     tile = jnp.where(lane < vocab, tile, NEG)
 
-    # per-tile top-2 + argmax
-    t1 = jnp.max(tile, axis=1)                            # (ROWS,)
-    ti = jnp.argmax(tile, axis=1).astype(jnp.int32) + vj * VTILE
-    masked = jnp.where(tile >= t1[:, None], NEG, tile)    # drop (all) maxima
-    t2 = jnp.max(masked, axis=1)
+    # per-tile top-2 + argmax; every per-row value is a (ROWS, 1) column
+    t1 = jnp.max(tile, axis=1, keepdims=True)
+    ti = jnp.argmax(tile, axis=1, keepdims=True).astype(jnp.int32) \
+        + vj * VTILE
+    masked = jnp.where(tile >= t1, NEG, tile)             # drop (all) maxima
+    t2 = jnp.max(masked, axis=1, keepdims=True)
     # duplicate maxima inside one tile: true top-2 equals the max
-    dup = jnp.sum((tile >= t1[:, None]).astype(jnp.int32), axis=1) > 1
+    dup = jnp.sum((tile >= t1).astype(jnp.int32), axis=1,
+                  keepdims=True) > 1
     t2 = jnp.where(dup, t1, t2)
 
     m_old, s_old, u_old = m_ref[...], s_ref[...], u_ref[...]
@@ -73,10 +79,10 @@ def _confidence_kernel(logits_ref, argmax_ref, maxp_ref, margin_ref,
     m_new = jnp.maximum(m_old, t1)
     # rescale old accumulators to the new max
     alpha = jnp.exp(m_old - m_new)                        # 0 when m_old=NEG
-    ex = jnp.exp(tile - m_new[:, None])
+    ex = jnp.exp(tile - m_new)
     ex = jnp.where(lane < vocab, ex, 0.0)
-    s_new = s_old * alpha + jnp.sum(ex, axis=1)
-    u_new = u_old * alpha + jnp.sum(tile * ex, axis=1)
+    s_new = s_old * alpha + jnp.sum(ex, axis=1, keepdims=True)
+    u_new = u_old * alpha + jnp.sum(tile * ex, axis=1, keepdims=True)
     # top-2 merge: candidates {m_old, m2_old, t1, t2} minus the new top-1
     take_new = t1 > m_old
     m2_new = jnp.where(take_new, jnp.maximum(m_old, t2),
@@ -121,12 +127,12 @@ def confidence_fused(logits: jnp.ndarray, interpret: bool = True
 
     kernel = functools.partial(_confidence_kernel, vocab=v, vtiles=vtiles)
     out_shape = [
-        jax.ShapeDtypeStruct((r,), jnp.int32),    # argmax
-        jax.ShapeDtypeStruct((r,), jnp.float32),  # max_prob
-        jax.ShapeDtypeStruct((r,), jnp.float32),  # margin
-        jax.ShapeDtypeStruct((r,), jnp.float32),  # neg_entropy
+        jax.ShapeDtypeStruct((r, 1), jnp.int32),    # argmax
+        jax.ShapeDtypeStruct((r, 1), jnp.float32),  # max_prob
+        jax.ShapeDtypeStruct((r, 1), jnp.float32),  # margin
+        jax.ShapeDtypeStruct((r, 1), jnp.float32),  # neg_entropy
     ]
-    row_spec = pl.BlockSpec((ROWS,), lambda i, j: (i,))
+    row_spec = pl.BlockSpec((ROWS, 1), lambda i, j: (i, 0))
     outs = pl.pallas_call(
         kernel,
         grid=(r // ROWS, vtiles),
@@ -134,17 +140,17 @@ def confidence_fused(logits: jnp.ndarray, interpret: bool = True
         out_specs=[row_spec] * 4,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((ROWS,), jnp.float32),  # m
-            pltpu.VMEM((ROWS,), jnp.float32),  # s
-            pltpu.VMEM((ROWS,), jnp.float32),  # u
-            pltpu.VMEM((ROWS,), jnp.float32),  # m2
-            pltpu.VMEM((ROWS,), jnp.int32),    # i1
+            pltpu.VMEM((ROWS, 1), jnp.float32),  # m
+            pltpu.VMEM((ROWS, 1), jnp.float32),  # s
+            pltpu.VMEM((ROWS, 1), jnp.float32),  # u
+            pltpu.VMEM((ROWS, 1), jnp.float32),  # m2
+            pltpu.VMEM((ROWS, 1), jnp.int32),    # i1
         ],
         interpret=interpret,
     )(flat)
     argmax, maxp, margin, negent = outs
 
     def unflat(a):
-        return a[:rows].reshape(shape[:-1])
+        return a[:rows, 0].reshape(shape[:-1])
 
     return (unflat(argmax), unflat(maxp), unflat(margin), unflat(negent))

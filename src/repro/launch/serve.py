@@ -12,7 +12,8 @@ the endpoint surface.
 
 ``--selftest`` instead boots the server on an ephemeral port, runs one
 streamed request through the blocking client, prints the events, and
-exits — the offline end-to-end sanity check.
+exits — the offline end-to-end sanity check.  It exits non-zero unless
+the request ends with status ``ok``.
 
 SIGTERM / SIGINT drain gracefully: admission stops (new submits answer
 503 + Retry-After), in-flight and queued requests get up to the drain
@@ -33,6 +34,7 @@ import jax
 from repro.configs import (DecodeConfig, RouterConfig, ServerConfig,
                            TrainConfig, default_block_size, get_config)
 from repro.data import CharTokenizer, TaskDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (ModelRouter, ServerThread, ServingClient,
                            ServingEngine, ServingServer)
 from repro.training import load, save
@@ -95,6 +97,7 @@ def main() -> None:
                     help="serve on an ephemeral port, run one streamed "
                          "request, print its events, exit")
     args = ap.parse_args()
+    enable_compile_cache()
 
     router = ModelRouter(RouterConfig(
         budget_bytes=args.budget_mb << 20))
@@ -189,18 +192,23 @@ def _selftest(router: ModelRouter, scfg: ServerConfig, tokenizer,
         prompt = ds.prompts_only(ds.eval_batch(1))[0].tolist()
         print(f"streaming one request (prompt "
               f"{tokenizer.decode(prompt)!r}) …")
+        status = None
         for name, event in client.generate_stream(prompt):
             if name == "block":
                 print(f"  block {event['block']} cols "
                       f"[{event['lo']}:{event['hi']}] "
                       f"-> {event.get('text', event['tokens'])!r}")
             else:
-                print(f"  {name}: status={event.get('status')} "
+                status = event.get("status")
+                print(f"  {name}: status={status} "
                       f"latency={event.get('latency_s', 0):.3f}s")
         print("metrics head:")
         print("\n".join(client.metrics_text().splitlines()[:8]))
     finally:
         handle.stop()
+    if status != "ok":
+        raise SystemExit(f"selftest FAILED: request ended with "
+                         f"status={status!r}")
     print("selftest OK")
 
 

@@ -19,7 +19,8 @@ token stream with M-RoPE position ids.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, List, NamedTuple, Optional, Tuple
+import functools
+from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +58,25 @@ def _stack(trees: List[Params]) -> Params:
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_layer(stacked: Params, layer: Params, i) -> Params:
+    return jax.tree.map(lambda s, a: s.at[i].set(a), stacked, layer)
+
+
+def _init_stacked(init_layer: Callable[[int], Params], n: int) -> Params:
+    """Stack ``n`` layers from ``init_layer(j)`` without holding the group
+    twice: each layer is built exactly as before, written into the donated
+    stacked buffer, and dropped — at full width the stack of a group is
+    most of the device's memory."""
+    first = init_layer(0)
+    out = jax.tree.map(lambda a: jnp.zeros((n,) + a.shape, a.dtype), first)
+    out = _put_layer(out, first, 0)
+    del first
+    for j in range(1, n):
+        out = _put_layer(out, init_layer(j), j)
+    return out
+
+
 # --------------------------------------------------------------------------
 # init
 # --------------------------------------------------------------------------
@@ -74,14 +94,16 @@ def init_model(rng, cfg: ModelConfig) -> Params:
                       "norm_f": init_norm(cfg)}
     groups = _layer_groups(cfg)
     params["blocks"] = [
-        _stack([blocks_lib.init_block(ks[1 + i], cfg, i) for i in g])
+        _init_stacked(lambda j, g=g: blocks_lib.init_block(
+            ks[1 + g[j]], cfg, g[j]), len(g))
         for g in groups]
     if cfg.is_encdec:
         ecfg = encoder_config(cfg)
         eks = jax.random.split(ks[-1], ecfg.num_layers + 1)
         params["encoder"] = {
-            "blocks": [_stack([blocks_lib.init_block(eks[i], ecfg, i)
-                               for i in g]) for g in _layer_groups(ecfg)],
+            "blocks": [_init_stacked(lambda j, g=g: blocks_lib.init_block(
+                eks[g[j]], ecfg, g[j]), len(g))
+                for g in _layer_groups(ecfg)],
             "norm_f": init_norm(ecfg),
         }
     if cfg.encdec is not None and cfg.encdec.frontend == "vision_stub":

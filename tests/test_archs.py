@@ -115,3 +115,35 @@ def test_decode_matches_forward_for_dense(rng):
     np.testing.assert_allclose(np.asarray(logits[0, 0]),
                                np.asarray(full_logits[0, 7]),
                                rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("arch", ["llada-8b", "deepseek-v2-236b",
+                                  "xlstm-125m", "whisper-medium"])
+def test_init_model_equals_per_layer_stack(arch, rng):
+    """``init_model`` writes each layer into a donated stacked buffer; its
+    values must equal stacking the per-layer inits, so checkpoints saved
+    from the stacked form stay loadable bit for bit."""
+    import numpy as np
+    from repro.models import blocks as blocks_lib
+    from repro.models.model import _layer_groups, encoder_config
+
+    cfg = get_config(arch).reduced(num_layers=4)
+    params = init_model(rng, cfg)
+    ks = jax.random.split(rng, cfg.num_layers + 3)
+
+    def stacked(keys, c):
+        return [jax.tree.map(lambda *xs: jnp.stack(xs), *[
+            blocks_lib.init_block(keys[i], c, i) for i in g])
+            for g in _layer_groups(c)]
+
+    want = [stacked(ks[1:], cfg)]
+    got = [params["blocks"]]
+    if cfg.is_encdec:
+        ecfg = encoder_config(cfg)
+        want.append(stacked(jax.random.split(ks[-1], ecfg.num_layers + 1),
+                            ecfg))
+        got.append(params["encoder"]["blocks"])
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

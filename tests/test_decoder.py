@@ -299,3 +299,65 @@ def test_model_fn_decoder_rejects_cache_policy(model):
     with pytest.raises(ValueError, match="params"):
         Decoder(model_fn, CFG, _dcfg(cache_policy="prefix")).generate(
             jax.random.PRNGKey(0), jnp.full((1, 4), 2, jnp.int32))
+
+
+# --------------------------------------------------------------------------
+# lower_blocks: the served per-block programs, lowered ahead of time
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("strategy,policy", [("fdm_a", "none"),
+                                             ("fdm", "none"),
+                                             ("fdm_a", "prefix")])
+def test_lower_blocks_is_the_served_program(model, strategy, policy):
+    """``lower_blocks`` lowers the exact runners ``generate_blocks``
+    dispatches: compiling it and then decoding builds no new runner, and
+    the compiled block program decodes the first block like the served
+    path does."""
+    params, _ = model
+    dcfg = _dcfg(strategy=strategy, cache_policy=policy)
+    prompt = jax.random.randint(jax.random.PRNGKey(3), (2, 8), 0,
+                                CFG.vocab_size - 1)
+    with decode_cache_scope() as cache:
+        dec = Decoder(params, CFG, dcfg)
+        lowered = dec.lower_blocks(2, 8)
+        assert set(lowered) == ({"block"} if policy == "none"
+                                else {"block", "refresh"})
+        compiled = {k: v.compile() for k, v in lowered.items()}
+        for c in compiled.values():
+            assert c.memory_analysis() is not None
+        built = cache.info().misses
+        blocks = dec.generate_blocks(jax.random.PRNGKey(5), prompt)
+        first = next(blocks)
+        for _ in blocks:
+            pass
+        assert cache.info().misses == built
+
+    from repro.core.masking import fully_masked
+    strat = resolve_strategy(strategy)
+    _, _, _, sched = dec._geometry()
+    x = fully_masked(CFG, prompt, dcfg.gen_length)
+    args = (x, jax.random.PRNGKey(5), jnp.int32(8), jnp.asarray(sched[0]),
+            jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32),
+            strat.init_carry_shaped(CFG, dcfg, 2, x.shape[1]))
+    if policy == "none":
+        out = compiled["block"](params, {}, *args)
+    else:
+        state = compiled["refresh"](params, x)
+        out = compiled["block"](params, *args, state)
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(first.x))
+
+    # shapes alone lower the same programs, with nothing on the device
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                          params)
+    with decode_cache_scope():
+        abstract = Decoder(shapes, CFG, dcfg).lower_blocks(2, 8)
+    assert {k: v.as_text() for k, v in abstract.items()} == \
+        {k: v.as_text() for k, v in lowered.items()}
+
+
+def test_lower_blocks_refuses_the_host_loop(model):
+    """A decode the server runs on the host step loop has no block
+    program: ``lower_blocks`` says so instead of lowering another one."""
+    params, _ = model
+    with pytest.raises(ValueError, match="host step loop"):
+        Decoder(params, CFG, _dcfg(fused_loop=False)).lower_blocks(2, 8)

@@ -14,11 +14,9 @@ from repro.configs import get_config
 from repro.models.model import init_decode_state, init_model
 from repro.parallel.sharding import cache_pspecs, param_pspecs
 
-# jax 0.4.37's AbstractMesh takes a single shape_tuple of (name, size)
-# pairs (newer jax split it into (shape, axis_names) — the call that used
-# to live here and broke collection)
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _leaf_specs(arch, mesh=MESH):
@@ -112,6 +110,6 @@ def test_dryrun_subprocess_smoke():
          "stablelm-3b", "--shape", "decode_32k", "--skip-full"],
         capture_output=True, text=True, timeout=560,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-        cwd="/root/repo")
+        cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "1 ok, 0 failed" in proc.stdout
