@@ -483,11 +483,6 @@ class ServerConfig:
     max_body_bytes: int = 1 << 20      # POST body cap (413 beyond; chunked
                                        # bodies are rejected outright)
     retry_after_s: float = 1.0         # Retry-After header on 429/503
-    profile_dir: str = ""              # non-empty = bracket each decoded
-                                       # batch with jax.profiler
-                                       # start_trace/stop_trace, dumping
-                                       # device profiles here (ops use:
-                                       # flip on, reproduce, flip off)
     supervisor: SupervisorConfig = SupervisorConfig()
     degrade: DegradeConfig = DegradeConfig()
 

@@ -47,6 +47,7 @@ def pallas_enabled(dcfg=None) -> bool:
     return bool(flag)
 
 
+@jax.named_scope("confidence")
 def score_logits(logits: jnp.ndarray,
                  use_kernel: bool = None) -> Scores:
     """One pass over the vocab axis -> all four per-position scores.

@@ -372,10 +372,10 @@ class Decoder:
         else:
             self._model_fn, self._params = None, model
         self._key, self._anchor = RunnerCache.key_for(model)
-        # optional telemetry hook ``(block_index, t_start_s, t_end_s)``
-        # fired around each KV-cache refresh on the blockwise path (the
-        # serving layer turns these into trace spans); None = free
-        self.on_cache_refresh: Optional[Callable] = None
+        # optional telemetry hook ``(name, cat, args) -> context
+        # manager`` timing each KV-cache refresh on the blockwise path
+        # (the serving layer's span hook); None = free
+        self.on_span: Optional[Callable] = None
 
     # -- geometry ----------------------------------------------------------
     def _geometry(self) -> Tuple[int, int, int, np.ndarray]:
@@ -880,7 +880,7 @@ class Decoder:
         # Each capture is one full forward, accounted host-side so all
         # three drivers report the same forward_equivalents.
         refresh, rlead = self._refresh_runner() if cached else (None, ())
-        hook = self.on_cache_refresh
+        hook = self.on_span
 
         def timed_refresh(canvas, blk):
             if hook is None:
@@ -888,10 +888,9 @@ class Decoder:
             # hook installed = serving-layer tracing: the extra sync is
             # paid only then, and the blockwise caller syncs per block
             # anyway (it materializes each block's tokens on host)
-            t0r = time.perf_counter()
-            st = refresh(*rlead, canvas)
-            jax.block_until_ready(st)
-            hook(blk, t0r, time.perf_counter())
+            with hook(f"cache_refresh[{blk}]", "decode", {"block": blk}):
+                st = refresh(*rlead, canvas)
+                jax.block_until_ready(st)
             return st
 
         state = timed_refresh(x, 0) if cached else None

@@ -69,20 +69,23 @@ def fdm_select(x: jnp.ndarray, logits: jnp.ndarray, active: jnp.ndarray,
     valid = jnp.any(sel_k, axis=-1)                           # (K, B)
 
     # ONE batched foreseeing forward over all K candidates
-    logits_c = model_fn(xc.reshape(k * b, l)).reshape(k, b, l, -1)
-    still_masked = (xc == cfg.mask_token_id)
-    c_glob = jax.vmap(global_confidence)(logits_c, still_masked)   # (K, B)
-    c_loc = jnp.sum(jnp.where(sel_k, c_local_log[None], 0.0), axis=-1)
-    total = jnp.where(valid, c_loc + c_glob, NEG)             # Eq. 15
-    winner = jnp.argmax(total, axis=0)                        # (B,)
-
-    win_commit = jnp.take_along_axis(
-        sel_k, winner[None, :, None], axis=0)[0]              # (B, L)
-    x_search = jnp.where(win_commit, s.argmax, x_safe)
+    with jax.named_scope("search"):
+        logits_c = model_fn(xc.reshape(k * b, l)).reshape(k, b, l, -1)
+        still_masked = (xc == cfg.mask_token_id)
+        c_glob = jax.vmap(global_confidence)(logits_c,
+                                             still_masked)    # (K, B)
+    with jax.named_scope("commit"):
+        c_loc = jnp.sum(jnp.where(sel_k, c_local_log[None], 0.0), axis=-1)
+        total = jnp.where(valid, c_loc + c_glob, NEG)         # Eq. 15
+        winner = jnp.argmax(total, axis=0)                    # (B,)
+        win_commit = jnp.take_along_axis(
+            sel_k, winner[None, :, None], axis=0)[0]          # (B, L)
+        x_search = jnp.where(win_commit, s.argmax, x_safe)
 
     # Λ = ∅ fallback: pure local top-n commit (no γ filter)
     x_local = commit_topn(x, s.max_prob, s.argmax, active, n_arr)
-    new_x = jnp.where(has_search[:, None], x_search, x_local)
+    with jax.named_scope("commit"):
+        new_x = jnp.where(has_search[:, None], x_search, x_local)
     return new_x, k   # K batch-equivalent foreseeing forwards
 
 

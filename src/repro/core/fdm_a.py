@@ -46,6 +46,7 @@ from repro.core.strategies import (ModelFn, Strategy, commit_topn,
                                    register_strategy)
 
 
+@jax.named_scope("plan")
 def fdm_a_plan(logits: jnp.ndarray, active: jnp.ndarray,
                dcfg: DecodeConfig):
     """Vectorized phase decision. Returns (n, gamma, need_search) per ex."""

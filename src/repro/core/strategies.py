@@ -77,6 +77,7 @@ def rank_desc(conf: jnp.ndarray) -> jnp.ndarray:
     return jnp.argsort(order, axis=-1)
 
 
+@jax.named_scope("commit")
 def commit_topn(x: jnp.ndarray, conf: jnp.ndarray, cand: jnp.ndarray,
                 eligible: jnp.ndarray, n: jnp.ndarray) -> jnp.ndarray:
     """Commit cand tokens at the top-n eligible positions per example.

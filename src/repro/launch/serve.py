@@ -15,6 +15,12 @@ streamed request through the blocking client, prints the events, and
 exits — the offline end-to-end sanity check.  It exits non-zero unless
 the request ends with status ``ok``.
 
+``--profiler-port N`` starts the JAX profiler server once, at start-up;
+an operator captures a window of the running server from it (TensorBoard's
+profile plugin or xprof, ``localhost:N``): device ops, and the serving
+stages as ``repro/<stage>`` host events on the same clock as the spans
+of ``GET /v1/trace/{rid}``.
+
 SIGTERM / SIGINT drain gracefully: admission stops (new submits answer
 503 + Retry-After), in-flight and queued requests get up to the drain
 deadline (``SupervisorConfig.drain_deadline_s``) to finish, leftover
@@ -96,8 +102,14 @@ def main() -> None:
     ap.add_argument("--selftest", action="store_true",
                     help="serve on an ephemeral port, run one streamed "
                          "request, print its events, exit")
+    ap.add_argument("--profiler-port", type=int, default=0,
+                    help="start the JAX profiler server on this port "
+                         "(0 = off): capture a window of the running "
+                         "server with TensorBoard or xprof")
     args = ap.parse_args()
     enable_compile_cache()
+    if args.profiler_port:
+        jax.profiler.start_server(args.profiler_port)
 
     router = ModelRouter(RouterConfig(
         budget_bytes=args.budget_mb << 20))

@@ -281,6 +281,7 @@ def mla_window(p: Params, x, positions, cfg: ModelConfig, cache: KVCache,
     return out, cache
 
 
+@jax.named_scope("attention")
 def attention_window(p: Params, x, positions, cfg: ModelConfig,
                      cache: KVCache, extend: bool = False
                      ) -> Tuple[jnp.ndarray, KVCache]:
@@ -383,6 +384,7 @@ def mla_cached(p: Params, x, positions, cfg: ModelConfig, cache: KVCache,
     return out.reshape(b, w, -1) @ p["wo"].astype(dt)
 
 
+@jax.named_scope("attention")
 def attention_capture(p: Params, x, positions, cfg: ModelConfig
                       ) -> Tuple[jnp.ndarray, KVCache]:
     if cfg.attention == "mla":
@@ -390,6 +392,7 @@ def attention_capture(p: Params, x, positions, cfg: ModelConfig
     return gqa_capture(p, x, positions, cfg)
 
 
+@jax.named_scope("attention")
 def attention_cached(p: Params, x, positions, cfg: ModelConfig,
                      cache: KVCache, win_start) -> jnp.ndarray:
     if cfg.attention == "mla":
@@ -492,6 +495,7 @@ def mla_decode(p: Params, x, positions, cfg: ModelConfig,
 # dispatch
 # --------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def attention_forward(p: Params, x, positions, cfg: ModelConfig,
                       attn_mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     if cfg.attention == "mla":
@@ -499,6 +503,7 @@ def attention_forward(p: Params, x, positions, cfg: ModelConfig,
     return gqa_forward(p, x, positions, cfg, attn_mask)
 
 
+@jax.named_scope("attention")
 def attention_decode(p: Params, x, positions, cfg: ModelConfig,
                      cache: KVCache) -> Tuple[jnp.ndarray, KVCache]:
     if cfg.attention == "mla":

@@ -143,6 +143,7 @@ def init_mlp(rng, cfg: ModelConfig, d_ff: Optional[int] = None) -> Params:
             "fc2": dense_init(ks[1], (ff, d))}
 
 
+@jax.named_scope("mlp")
 def apply_mlp(p: Params, x, cfg: ModelConfig):
     dt = x.dtype
     ff_spec = ("dp", None, "tp") if x.ndim == 3 else (None, "tp")
@@ -167,6 +168,7 @@ def init_embed(rng, cfg: ModelConfig) -> Params:
     return p
 
 
+@jax.named_scope("embed")
 def embed_tokens(p: Params, tokens, cfg: ModelConfig, positions=None):
     x = jnp.take(p["tok"], tokens, axis=0).astype(compute_dtype(cfg))
     if "pos" in p and positions is not None:
@@ -175,6 +177,7 @@ def embed_tokens(p: Params, tokens, cfg: ModelConfig, positions=None):
     return x
 
 
+@jax.named_scope("lm_head")
 def lm_head(p: Params, x, cfg: ModelConfig, vocab_sharded: bool = False):
     """``vocab_sharded=True`` keeps the logits sharded on the vocab axis
     (consumers must use reduction-only scoring, see

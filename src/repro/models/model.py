@@ -287,6 +287,7 @@ def decode_step(params: Params, token: jnp.ndarray, position: jnp.ndarray,
 # fixed-shape block cache (cache_policy = prefix | dual)
 # --------------------------------------------------------------------------
 
+@jax.named_scope("capture_cache")
 def capture_cache(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
                   enc_out: Optional[jnp.ndarray] = None) -> DecodeState:
     """One full bidirectional pass over the canvas (B, total) capturing
@@ -324,6 +325,7 @@ def capture_cache(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     return DecodeState(layer_states=tuple(states), enc_out=enc_out)
 
 
+@jax.named_scope("forward_cached")
 def forward_cached(params: Params, tokens: jnp.ndarray, win_start,
                    state: DecodeState, cfg: ModelConfig) -> jnp.ndarray:
     """Score a W-row live window (B, W) at traced offset ``win_start``

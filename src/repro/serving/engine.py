@@ -46,11 +46,18 @@ to the constructor to observe each committed block of a batch as it lands
 (the natural SSE grain for diffusion decoding — tokens inside a block
 finalize together).  ``x`` is the live device canvas; don't block in the
 callback.
+
+Tracing: ``on_span(requests, name, cat, args)`` (installed by the async
+scheduler) returns a context manager that times one host stage of
+``decode_batch_blocks`` — ``dispatch[i]``, ``device_wait[i]``,
+``validate[i]``, ``finish``, and the decoder's ``cache_refresh[i]`` — on
+the thread that runs it (``serving/tracing.py`` has the span tree).
+Request times (``submit_time``, ``finish_time``, deadlines) are on the
+same clock as the spans, ``tracing.now()``.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Callable, Deque, Dict, Iterator, List, Optional, Tuple
 
@@ -62,6 +69,7 @@ from repro.configs.base import DecodeConfig, ModelConfig
 from repro.core.decoder import Decoder, SampleStats, validate_cache_policy
 from repro.core.strategies import resolve_strategy
 from repro.serving.faults import FaultInjector, validate_block_tokens
+from repro.serving import tracing
 
 
 @dataclasses.dataclass
@@ -73,7 +81,7 @@ class Request:
     submit_time: float = 0.0
     finish_time: float = 0.0
     dcfg: Optional[DecodeConfig] = None   # effective per-request config
-    deadline: Optional[float] = None      # absolute perf_counter() time by
+    deadline: Optional[float] = None      # absolute tracing.now() time by
                                           # which decoding must have STARTED
     cancelled: bool = False
     expired: bool = False
@@ -126,9 +134,9 @@ class ServingEngine:
         self.length_bucket = max(length_bucket, 1)
         self.on_block_committed = on_block_committed
         # observability hook (installed by the async scheduler):
-        # ``(requests, block_index, t_start_s, t_end_s)`` per KV-cache
-        # refresh inside ``decode_batch_blocks``
-        self.on_cache_refresh: Optional[Callable] = None
+        # ``(requests, name, cat, args) -> context manager`` around each
+        # host stage of ``decode_batch_blocks`` (module docstring)
+        self.on_span: Optional[Callable] = None
         self.fault_injector = fault_injector
         self.queue: Deque[Request] = deque()
         self.done: Dict[int, Request] = {}
@@ -189,7 +197,7 @@ class ServingEngine:
                 f"need at least one step each")
         rid = self._next_id
         self._next_id += 1
-        now = time.perf_counter()
+        now = tracing.now()
         self.queue.append(Request(
             rid=rid, prompt=np.asarray(prompt), submit_time=now, dcfg=dcfg,
             deadline=None if deadline_s is None else now + deadline_s))
@@ -205,7 +213,7 @@ class ServingEngine:
             if req.rid == rid:
                 self.queue.remove(req)
                 req.cancelled = True
-                req.finish_time = time.perf_counter()
+                req.finish_time = tracing.now()
                 self.done[rid] = req
                 return True
         return False
@@ -264,7 +272,7 @@ class ServingEngine:
         ``result(rid)`` and excluded from throughput accounting exactly
         like a cancelled one."""
         req.failed = True
-        req.finish_time = time.perf_counter() if now is None else now
+        req.finish_time = tracing.now() if now is None else now
         self.done[req.rid] = req
 
     def adopt(self, old: "ServingEngine") -> None:
@@ -283,7 +291,7 @@ class ServingEngine:
     def reap_expired(self, now: Optional[float] = None) -> List[Request]:
         """Drop queued requests whose deadline passed; returns them (also
         recorded in ``done`` with ``expired=True``)."""
-        now = time.perf_counter() if now is None else now
+        now = tracing.now() if now is None else now
         expired = [r for r in self.queue
                    if r.deadline is not None and now > r.deadline]
         for req in expired:
@@ -399,30 +407,41 @@ class ServingEngine:
         bi = inj.begin_batch() if inj is not None else 0
         rids = [r.rid for r in batch.requests]
         dec = self._decoder_for(batch.dcfg)
-        if self.on_cache_refresh is not None:
-            # decoders are per-config and the engine decodes one batch
-            # at a time, so pointing the decoder hook at this batch's
-            # requests is race-free
-            dec.on_cache_refresh = (
-                lambda blk, t0, t1, _reqs=batch.requests:
-                self.on_cache_refresh(_reqs, blk, t0, t1))
-        else:
-            dec.on_cache_refresh = None
-        blocks = dec.generate_blocks(batch.rng, jnp.asarray(batch.prompts))
+        hook = self.on_span
+
+        def stage(name, cat="engine", args=None):
+            if hook is None:
+                return tracing.NO_SPAN
+            return hook(batch.requests, name, cat, args)
+
+        # decoders are per-config and the engine decodes one batch at a
+        # time, so pointing the decoder hook at this batch's requests is
+        # race-free
+        dec.on_span = stage if hook is not None else None
+        num_blocks = batch.dcfg.gen_length // batch.dcfg.block_size
+        blocks = None
         block_index = 0
         while True:
             if inj is not None:
                 inj.before_block(bi, rids, block_index)
-            try:
-                ev = next(blocks)
-            except StopIteration as fin:
-                out, stats = fin.value
-                return self._finish_batch(batch, out, stats)
+            name = "finish" if block_index == num_blocks \
+                else f"dispatch[{block_index}]"
+            with stage(name):
+                if blocks is None:
+                    blocks = dec.generate_blocks(batch.rng,
+                                                 jnp.asarray(batch.prompts))
+                try:
+                    ev = next(blocks)
+                except StopIteration as fin:
+                    out, stats = fin.value
+                    return self._finish_batch(batch, out, stats)
+            with stage(f"device_wait[{block_index}]"):
+                tokens = np.asarray(ev.x[:, ev.lo:ev.hi])
+            with stage(f"validate[{block_index}]"):
+                if inj is not None:
+                    tokens = inj.filter_tokens(bi, rids, ev.block, tokens)
+                validate_block_tokens(tokens, self.cfg.vocab_size)
             block_index += 1
-            tokens = np.asarray(ev.x[:, ev.lo:ev.hi])
-            if inj is not None:
-                tokens = inj.filter_tokens(bi, rids, ev.block, tokens)
-            validate_block_tokens(tokens, self.cfg.vocab_size)
             if self.on_block_committed is not None:
                 self.on_block_committed(batch.requests, ev.block, ev.lo,
                                         ev.hi, ev.x)
@@ -431,7 +450,7 @@ class ServingEngine:
     def _finish_batch(self, batch: Batch, out, stats: SampleStats
                       ) -> List[int]:
         out = np.asarray(jax.device_get(out))
-        now = time.perf_counter()
+        now = tracing.now()
         real = len(batch.requests)
         rows = len(batch.prompts)
         for i, req in enumerate(batch.requests):

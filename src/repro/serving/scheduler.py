@@ -63,7 +63,6 @@ resumed again).
 from __future__ import annotations
 
 import asyncio
-import time
 from collections import deque
 from typing import AsyncIterator, Callable, Deque, Dict, List, Optional
 
@@ -76,7 +75,8 @@ from repro.serving.metrics import MetricsRegistry
 from repro.serving.supervisor import (Backoff, CircuitBreaker,
                                       DegradationLadder, WatchdogTimeout,
                                       bisect, classify_failure)
-from repro.serving.tracing import Span, TraceStore
+from repro.serving.tracing import (Span, TraceStore, annotate,
+                                   compile_counter, new_span_id, now)
 
 
 class QueueFullError(RuntimeError):
@@ -129,8 +129,7 @@ class AsyncScheduler:
                  rebuild_engine: Optional[
                      Callable[[], ServingEngine]] = None,
                  registry: Optional[MetricsRegistry] = None,
-                 model: str = "",
-                 profile_dir: str = ""):
+                 model: str = ""):
         self.engine = engine
         self.max_queue_depth = max_queue_depth
         self.default_deadline_s = default_deadline_s
@@ -139,11 +138,14 @@ class AsyncScheduler:
         self.dgcfg = dgcfg
         self.rebuild_engine = rebuild_engine
         self.model = model
-        self.profile_dir = profile_dir
         # request tracing: span records at every lifecycle stage, same
         # retention horizon as the event streams (retired together)
         self.trace_store = TraceStore(retain=self.stream_retain)
-        self._install_refresh_hook(engine)
+        # the id of the decode round trip in flight: the parent of the
+        # engine stages its executor thread records
+        self._round_trip: Optional[int] = None
+        self._compiles = compile_counter()
+        self._install_span_hook(engine)
         # metrics registry (optional — standalone schedulers skip it):
         # the scheduler owns the per-request distributions the flat
         # counters cannot express
@@ -376,11 +378,12 @@ class AsyncScheduler:
         raise RuntimeError(f"stream {rid} ended without a terminal event")
 
     def trace(self, rid: int) -> Dict:
-        """Chrome trace-event JSON for one request: the scheduler's span
-        records (queue wait, batch assembly, per-block decode, cache
-        refresh, emit) plus — when the request decoded with
-        ``trace=true`` — the on-device per-step counters.  ``KeyError``
-        for a rid never selected into a batch or already retired."""
+        """Chrome trace-event JSON for one request: the span records
+        (queue wait, batch assembly, per-block decode round trips and the
+        engine stages inside them, fan-out, emit) plus — when the request
+        decoded with ``trace=true`` — the on-device per-step counters.
+        ``KeyError`` for a rid never selected into a batch or already
+        retired."""
         return self.trace_store.chrome(rid)
 
     def metrics(self) -> Dict:
@@ -398,18 +401,17 @@ class AsyncScheduler:
                 "engine": self.engine.summary()}
 
     # -- internals ---------------------------------------------------------
-    def _install_refresh_hook(self, engine: ServingEngine) -> None:
-        """KV-cache refreshes happen inside the decoder between blocks;
-        the engine surfaces them through this hook so the trace shows
-        refresh time separately from decode time."""
-        engine.on_cache_refresh = self._on_cache_refresh
+    def _install_span_hook(self, engine: ServingEngine) -> None:
+        """The engine's host stages (dispatch, device wait, validation,
+        finish, and the decoder's cache refreshes) run on the executor
+        thread; the engine times each through this hook, so the trace
+        splits a decode round trip into the stages that fill it."""
+        engine.on_span = self._on_span
 
-    def _on_cache_refresh(self, requests, blk: int, t0: float,
-                          t1: float) -> None:
-        span = Span(f"cache_refresh[{blk}]", "decode", t0, t1,
-                    {"block": blk})
-        for req in requests:
-            self.trace_store.add(req.rid, span)
+    def _on_span(self, requests, name: str, cat: str,
+                 args: Optional[Dict] = None):
+        return self.trace_store.span([r.rid for r in requests], name, cat,
+                                     args, parent=self._round_trip)
 
     def _emit(self, rid: int, event: Dict) -> None:
         stream = self._streams.get(rid)
@@ -450,8 +452,10 @@ class AsyncScheduler:
                 # starting — it must not see that window as evictable
                 # idleness
                 self._decoding = True
-                t_sel = time.perf_counter()
-                batch = self.engine.select_batch()
+                with annotate("batch_assembly"):
+                    t_sel = now()
+                    batch = self.engine.select_batch()
+                    t_asm = now()
                 if batch is None:
                     self._decoding = False
                     self._wake.clear()
@@ -463,16 +467,14 @@ class AsyncScheduler:
                         await self._wake.wait()
                     continue
                 self.counters["batches"] += 1
-                t_asm = time.perf_counter()
-                asm_args = {"batch_size": len(batch.requests),
+                asm = Span("batch_assembly", "serving", t_sel, t_asm,
+                           {"batch_size": len(batch.requests),
                             "strategy": batch.dcfg.strategy,
-                            "cache_policy": batch.dcfg.cache_policy}
+                            "cache_policy": batch.dcfg.cache_policy})
                 for req in batch.requests:
                     self.trace_store.add(req.rid, Span(
                         "queue_wait", "serving", req.submit_time, t_sel))
-                    self.trace_store.add(req.rid, Span(
-                        "batch_assembly", "serving", t_sel, t_asm,
-                        asm_args))
+                    self.trace_store.add(req.rid, asm)
                     if self._m_queue_wait is not None:
                         self._m_queue_wait.labels(model=self.model) \
                             .observe(t_sel - req.submit_time)
@@ -509,18 +511,15 @@ class AsyncScheduler:
         while True:
             self._inflight.clear()
             self._inflight.update(r.rid for r in batch.requests)
-            progress = {"blocks": 0}
+            progress = {"blocks": 0, "finish": None}
             try:
-                profiling = self._start_profiler()
-                try:
-                    await self._drive_batch(loop, batch, progress)
-                finally:
-                    self._stop_profiler(profiling)
+                await self._drive_batch(loop, batch, progress)
                 self.breaker.record_success()
                 for req in batch.requests:
                     self.counters["finished"] += 1
                     self._record_finished(req, batch)
-                    self._emit(req.rid, self._done_event(req))
+                    self._emit(req.rid,
+                               self._done_event(req, progress["finish"]))
                 return
             except _AbandonBatch:
                 raise
@@ -581,77 +580,65 @@ class AsyncScheduler:
             tokens_generated=int(req.stats.tokens_generated)
             if req.stats else 0)
 
-    def _start_profiler(self) -> bool:
-        """``ServerConfig.profile_dir`` (non-empty) brackets each decoded
-        batch with a ``jax.profiler`` device trace — the heavyweight
-        opt-in complement to the always-cheap span records."""
-        if not self.profile_dir:
-            return False
-        import jax
-        try:
-            jax.profiler.start_trace(self.profile_dir)
-            return True
-        except Exception:
-            # a profiler session may already be live (concurrent model,
-            # external harness): tracing is telemetry, never a reason to
-            # fail the decode
-            return False
-
-    def _stop_profiler(self, started: bool) -> None:
-        if not started:
-            return
-        import jax
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
-
     async def _drive_batch(self, loop, batch: Batch, progress: Dict
                            ) -> None:
         """Drive one decode attempt block by block, under the watchdog;
-        fans block events out to the per-request streams."""
+        fans block events out to the per-request streams.  Each executor
+        round trip is a ``decode_block[i]`` span (``decode_finish`` for
+        the last), the parent of the engine stages it ran and of the
+        block's ``fanout[i]``; ``compiles`` counts the programs traced,
+        compiled or loaded in the process meanwhile."""
         svc = self.svcfg
         rids = [r.rid for r in batch.requests]
+        num_blocks = batch.dcfg.gen_length // batch.dcfg.block_size
         blocks = self.engine.decode_batch_blocks(batch)
         while True:
-            t_blk = time.perf_counter()
-            fut = loop.run_in_executor(None, _drive, blocks)
-            if svc.watchdog_s > 0:
-                try:
-                    kind, payload = await asyncio.wait_for(
-                        asyncio.shield(fut), svc.watchdog_s)
-                except asyncio.TimeoutError:
-                    # the resumption keeps running on its executor
-                    # thread but is never resumed again; the engine may
-                    # be wedged, so this is engine-fatal
-                    fut.add_done_callback(_retrieve)
-                    self.counters["watchdog_timeouts"] += 1
-                    raise WatchdogTimeout(
-                        f"block exceeded the {svc.watchdog_s:g}s "
-                        f"watchdog") from None
-            else:
-                kind, payload = await fut
+            span_id = self._round_trip = new_span_id()
+            compiled = self._compiles.total()
+            index = progress["blocks"]
+            with annotate("decode_finish" if index == num_blocks
+                          else f"decode_block[{index}]"):
+                t_blk = now()
+                fut = loop.run_in_executor(None, _drive, blocks)
+                if svc.watchdog_s > 0:
+                    try:
+                        kind, payload = await asyncio.wait_for(
+                            asyncio.shield(fut), svc.watchdog_s)
+                    except asyncio.TimeoutError:
+                        # the resumption keeps running on its executor
+                        # thread but is never resumed again; the engine
+                        # may be wedged, so this is engine-fatal
+                        fut.add_done_callback(_retrieve)
+                        self.counters["watchdog_timeouts"] += 1
+                        raise WatchdogTimeout(
+                            f"block exceeded the {svc.watchdog_s:g}s "
+                            f"watchdog") from None
+                else:
+                    kind, payload = await fut
+                t_end = now()
+            compiled = self._compiles.total() - compiled
             if kind == "done":
-                final = Span("decode_finish", "decode", t_blk,
-                             time.perf_counter())
-                for rid in rids:
-                    self.trace_store.add(rid, final)
+                self.trace_store.add_all(rids, Span(
+                    "decode_finish", "decode", t_blk, t_end,
+                    {"compiles": compiled}, span_id))
+                progress["finish"] = span_id
                 return
             blk, lo, hi, tokens = payload
-            span = Span(f"decode_block[{blk}]", "decode", t_blk,
-                        time.perf_counter(), {"block": blk})
-            for rid in rids:
-                self.trace_store.add(rid, span)
+            self.trace_store.add_all(rids, Span(
+                f"decode_block[{blk}]", "decode", t_blk, t_end,
+                {"block": blk, "compiles": compiled}, span_id))
             self.counters["blocks"] += 1
             progress["blocks"] += 1
-            for i, req in enumerate(batch.requests):
-                # rebase to the request's own coordinates (mask pad
-                # columns sit left of its prompt)
-                self._emit(req.rid, {
-                    "type": "block", "rid": req.rid, "block": blk,
-                    "lo": lo - req.pad_cols,
-                    "hi": hi - req.pad_cols,
-                    "tokens": tokens[i].tolist()})
+            with self.trace_store.span(rids, f"fanout[{blk}]", "serving",
+                                       parent=span_id):
+                for i, req in enumerate(batch.requests):
+                    # rebase to the request's own coordinates (mask pad
+                    # columns sit left of its prompt)
+                    self._emit(req.rid, {
+                        "type": "block", "rid": req.rid, "block": blk,
+                        "lo": lo - req.pad_cols,
+                        "hi": hi - req.pad_cols,
+                        "tokens": tokens[i].tolist()})
             if self._abandon:
                 raise _AbandonBatch()
 
@@ -671,9 +658,9 @@ class AsyncScheduler:
             if rebuilt is not None:
                 rebuilt.adopt(self.engine)
                 self.engine = rebuilt
-                # hooks are NOT adopted — re-point the refresh spans at
+                # hooks are NOT adopted — re-point the stage spans at
                 # the engine that will actually decode from here on
-                self._install_refresh_hook(rebuilt)
+                self._install_span_hook(rebuilt)
                 self.counters["engine_rebuilds"] += 1
         survivors = []
         for req in batch.requests:
@@ -692,11 +679,13 @@ class AsyncScheduler:
             self.counters["requeued"] += len(survivors)
             self._wake.set()
 
-    def _done_event(self, req: Request) -> Dict:
+    def _done_event(self, req: Request,
+                    parent: Optional[int] = None) -> Dict:
         # the "emit" span covers payload construction (tolist dominates
         # fan-out cost) and lands BEFORE _emit, whose terminal event
         # retires the trace — nothing may attach after retirement
-        with self.trace_store.span(req.rid, "emit", "serving"):
+        with self.trace_store.span(req.rid, "emit", "serving",
+                                   parent=parent):
             return {"type": "done", "rid": req.rid, "status": "ok",
                     "final": True,
                     "tokens": req.result.tolist(),

@@ -33,14 +33,17 @@ web framework, zero new runtime dependencies.  The endpoint surface:
 * ``GET /healthz`` — liveness + per-model health (``ok`` / ``degraded``
   after a circuit-breaker engine rebuild / ``draining``) + queue depths.
 * ``GET /v1/trace/{rid}?model=name`` — Chrome trace-event JSON for one
-  request: scheduler lifecycle spans (queue wait, batch assembly,
-  per-block decode, cache refresh, emit) and — when submitted with
-  ``trace: true`` — the on-device per-step commit/revocation/skip
-  counters.  Open in Perfetto or render with ``tools/trace_view.py``.
+  request: lifecycle spans (queue wait, batch assembly, per-block
+  decode round trips and the engine's host stages inside them, cache
+  refresh, fan-out, emit), on the profiler's clock, and — when
+  submitted with ``trace: true`` — the on-device per-step
+  commit/revocation/skip counters.  Open in Perfetto or render with
+  ``tools/trace_view.py``.
 * ``GET /metrics`` — Prometheus text exposition (format 0.0.4, with
   HELP/TYPE) from a real ``MetricsRegistry``: the seed-era router/
   scheduler/decode-cache series plus latency, queue-wait, queue-depth
-  and tokens-per-request histograms and per-strategy decode counters.
+  and tokens-per-request histograms, per-strategy decode counters and
+  the process's compile counts (``repro_compiles_total{event}``).
 
 Backpressure answers carry ``Retry-After``: 429 at queue depth, 503
 while draining for shutdown.  Bodies are bounded by Content-Length
@@ -69,6 +72,7 @@ from repro.serving.metrics import (CONTENT_TYPE, Family, MetricsRegistry)
 from repro.serving.router import ModelRouter
 from repro.serving.scheduler import (AsyncScheduler, QueueFullError,
                                      SchedulerDrainingError)
+from repro.serving.tracing import compile_counter
 
 _MAX_HEADER_BYTES = 32 * 1024
 
@@ -221,8 +225,7 @@ class ServingServer:
                 svcfg=self.scfg.supervisor,
                 dgcfg=self.scfg.degrade,
                 rebuild_engine=lambda n=name: self._rebuild_engine(n),
-                registry=self.registry, model=name,
-                profile_dir=self.scfg.profile_dir)
+                registry=self.registry, model=name)
             await sched.start()
             self._scheds[name] = sched
             self.router.set_busy_probe(
@@ -628,6 +631,11 @@ class ServingServer:
             fam(f"decode_cache_{fld}", "gauge",
                 f"Decode runner cache: {fld}.",
                 [({}, getattr(cache, fld))])
+        fam("compiles_total", "counter",
+            "Programs traced, compiled or loaded from the persistent "
+            "cache in this process, by event.",
+            [({"event": k}, v)
+             for k, v in compile_counter().snapshot().items()])
         return fams
 
     # -- response helpers --------------------------------------------------

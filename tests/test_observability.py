@@ -3,11 +3,15 @@ text exposition), on-device step telemetry (``dcfg.trace`` →
 ``SampleStats.trace``), request tracing through the serving stack
 (``/v1/trace/{rid}`` Chrome trace-event JSON), and the ANA105 telemetry
 contract."""
+import asyncio
 import dataclasses
+import glob
 import importlib.util
 import io
 import json
 import os
+import re
+import sys
 import threading
 
 import jax
@@ -20,11 +24,12 @@ from repro.core import Decoder, decode_cache_scope, decode_cache_info
 from repro.core.decoder import SampleStats
 from repro.core.tracebuffer import DecodeTrace, trace_capacity, tracing
 from repro.models.model import init_model
-from repro.serving import (ModelRouter, ServerError, ServerThread,
-                           ServingClient, ServingEngine)
+from repro.serving import (AsyncScheduler, ModelRouter, ServerError,
+                           ServerThread, ServingClient, ServingEngine)
 from repro.serving.metrics import (CONTENT_TYPE, Family, MetricsRegistry,
                                    escape_label_value, format_value)
-from repro.serving.tracing import Span, TraceStore, chrome_trace
+from repro.serving.tracing import (CompileCounter, Span, TraceStore,
+                                   chrome_trace, compile_counter)
 
 CFG = get_config("llada-8b").reduced()
 DCFG = DecodeConfig(gen_length=16, block_size=8, steps=16,
@@ -369,6 +374,195 @@ def test_concurrent_metrics_scrape_during_decode(client):
     assert texts and all("repro_up 1" in x for x in texts)
     final = client.metrics_text()
     assert 'repro_tokens_per_request_count{model="tiny"}' in final
+
+
+# --------------------------------------------------------------------------
+# host stages on the profiler's clock, the span tree, the compile counter
+# --------------------------------------------------------------------------
+
+def _serve_one_batch(params, dcfg, n=2):
+    """``n`` requests submitted before the worker starts, so one batch
+    decodes them all; returns each request's exported trace events."""
+    async def main():
+        sched = AsyncScheduler(ServingEngine(params, CFG, dcfg,
+                                             max_batch=4))
+        rids = [sched.submit(np.array(PROMPT)) for _ in range(n)]
+        await sched.start()
+        for rid in rids:
+            assert (await sched.result(rid))["status"] == "ok"
+        traces = [sched.trace(rid)["traceEvents"] for rid in rids]
+        await sched.close()
+        return traces
+    return asyncio.run(main())
+
+
+def _spans(events):
+    return [e for e in events if e.get("ph") == "X"
+            and e.get("cat") != "device"]
+
+
+def test_span_parents_form_the_stated_tree(params):
+    dcfg = dataclasses.replace(DCFG, cache_policy="prefix")
+    events = _spans(_serve_one_batch(params, dcfg, n=1)[0])
+    by_name = {e["name"]: e for e in events}
+    nblocks = DCFG.gen_length // DCFG.block_size
+
+    def parent(name):
+        return by_name[name]["args"]["parent"]
+
+    for root in ("queue_wait", "batch_assembly", "decode_finish"):
+        assert parent(root) is None
+    for i in range(nblocks):
+        blk = by_name[f"decode_block[{i}]"]
+        assert blk["args"]["parent"] is None
+        assert blk["args"]["block"] == i and "compiles" in blk["args"]
+        for stage in ("dispatch", "cache_refresh", "device_wait",
+                      "validate", "fanout"):
+            assert parent(f"{stage}[{i}]") == blk["args"]["id"], stage
+        # the engine's stages run inside the round trip that holds them
+        for stage in ("dispatch", "cache_refresh", "device_wait",
+                      "validate"):
+            e = by_name[f"{stage}[{i}]"]
+            assert blk["ts"] <= e["ts"] and \
+                e["ts"] + e["dur"] <= blk["ts"] + blk["dur"] + 1.0
+        assert by_name[f"fanout[{i}]"]["ts"] >= blk["ts"] + blk["dur"] - 1.0
+    fin = by_name["decode_finish"]
+    assert "compiles" in fin["args"]
+    assert parent("finish") == parent("emit") == fin["args"]["id"]
+    # cache_refresh keeps its name, category and args
+    assert by_name["cache_refresh[0]"]["cat"] == "decode"
+    assert by_name["cache_refresh[0]"]["args"]["block"] == 0
+    ids = [e["args"]["id"] for e in events]
+    assert len(ids) == len(set(ids))
+
+
+def test_shared_spans_export_identical_ts_across_a_batch(params):
+    a, b = (_spans(t) for t in _serve_one_batch(params, DCFG, n=2))
+    shared = {e["name"]: e for e in a}
+    other = {e["name"]: e for e in b}
+    names = set(shared) - {"queue_wait", "emit"}
+    assert {"batch_assembly", "decode_block[0]", "dispatch[0]",
+            "device_wait[1]", "finish"} <= names
+    for name in names:
+        assert (shared[name]["ts"], shared[name]["dur"],
+                shared[name]["args"]["id"]) == \
+            (other[name]["ts"], other[name]["dur"],
+             other[name]["args"]["id"]), name
+    # one clock for every request: no per-request origin
+    assert shared["queue_wait"]["ts"] != 0.0 and \
+        other["queue_wait"]["ts"] != 0.0
+
+
+def _host_events(xplane: str):
+    """{name: [(start_ns, end_ns)]} of the host planes' events on the
+    profiler's absolute clock: each event's offset from the session's
+    ``profile_start_time`` (the ``Task Environment`` plane) added back."""
+    pd = jax.profiler.ProfileData.from_file(xplane)
+    start = [dict(p.stats)["profile_start_time"] for p in pd.planes
+             if p.name == "Task Environment"][0]
+    out = {}
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (start + ev.start_ns,
+                     start + ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_profiler_window_shows_the_stages_on_the_span_clock(client,
+                                                            tmp_path):
+    client.generate(PROMPT, wait=True)              # compiled already
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        done = client.generate(PROMPT, wait=True)
+    finally:
+        jax.profiler.stop_trace()
+    spans = {e["name"]: e for e in _spans(
+        client.trace(done["rid"])["traceEvents"])}
+    host = _host_events(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                                  recursive=True)[0])
+    for stage in ("dispatch[0]", "device_wait[0]", "fanout[0]", "finish",
+                  "decode_block[0]", "emit"):
+        (lo, hi), = host[f"repro/{stage}"]
+        span = spans[stage]
+        assert abs(lo - span["ts"] * 1e3) < 1e6, stage         # 1 ms
+        assert abs(hi - (span["ts"] + span["dur"]) * 1e3) < 1e6, stage
+    # recorded after the fact: no mirror
+    assert "repro/queue_wait" not in host
+
+
+def test_compile_counter_sees_a_forced_recompile(client):
+    counter = compile_counter()
+    f = jax.jit(lambda x: x * 3 + 1)
+    before = counter.total()
+    f(np.ones((7, 3), np.float32))
+    seen = counter.total()
+    assert seen > before
+    f(np.ones((7, 3), np.float32))                 # cached: nothing new
+    assert counter.total() == seen
+    f(np.ones((5, 3), np.float32))                 # a new shape recompiles
+    assert counter.total() > seen
+    # a new decode config compiles inside its first round trip; the same
+    # config again compiles nothing
+    spans = []
+    for _ in range(2):
+        done = client.generate(PROMPT, steps=8, wait=True)
+        spans.append({e["name"]: e for e in _spans(
+            client.trace(done["rid"])["traceEvents"])})
+    assert spans[0]["decode_block[0]"]["args"]["compiles"] > 0
+    assert all(s["args"]["compiles"] == 0 for s in spans[1].values()
+               if s["name"].startswith(("decode_block", "decode_finish")))
+    text = client.metrics_text()
+    for event in ("trace", "backend_compile", "cache_load"):
+        assert f'repro_compiles_total{{event="{event}"}}' in text
+
+
+def test_compile_counter_loses_no_count_across_threads():
+    """Compiles report from whichever thread compiles: concurrent
+    reports all count."""
+    counter = CompileCounter()
+    event = "/jax/core/compile/backend_compile_duration"
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(
+            target=lambda: [counter.on_event(event, 0.1)
+                            for _ in range(2000)]) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30.0)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert counter.snapshot() == {"trace": 0, "backend_compile": 32000,
+                                  "cache_load": 0}
+
+
+@pytest.mark.parametrize("policy,scopes", [
+    ("none", {"embed", "attention", "mlp", "lm_head", "plan", "search",
+              "commit", "confidence"}),
+    ("prefix", {"capture_cache", "forward_cached", "embed", "attention",
+                "mlp", "lm_head", "plan", "search", "commit",
+                "confidence"})])
+def test_served_block_program_carries_named_scopes(params, policy, scopes):
+    dcfg = DecodeConfig(gen_length=16, block_size=8, steps=16, k1=2,
+                        strategy="fdm_a", cache_policy=policy)
+    lowered = Decoder(params, CFG, dcfg).lower_blocks(2, 8)
+    text = "\n".join(low.compiler_ir("hlo").get_hlo_module().to_string()
+                     for low in lowered.values())
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    assert scopes <= {seg for p in paths for seg in p.split("/")}
+    # the K-candidate forward's head sits under the search scope, the
+    # plan's scoring under the plan scope
+    assert any("/search/" in p and "/lm_head/" in p for p in paths)
+    assert any("/plan/confidence/" in p for p in paths)
 
 
 # --------------------------------------------------------------------------
