@@ -9,32 +9,57 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.confidence import ROWS, VTILE, confidence_fused
+from repro.kernels.confidence import (LANES, MAX_CHUNKS, VMEM_LIMIT,
+                                     confidence_fused, tiling, vmem_bytes)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import (attention_ref, confidence_ref,
                                selective_scan_ref)
 from repro.kernels.selective_scan import selective_scan
 
-CONF_SHAPES = [
-    ((4, 7), 1000),        # ragged rows and vocab
-    ((2, 3), VTILE + 3),   # one lane over a tile boundary
-    ((5,), 2 * VTILE),     # exact tiles
-    ((2, 2), 130),         # single partial tile
-    ((ROWS + 1, 2), 513),  # row padding
-]
+# (leading shape, vocab, ties): each takes the tiling rule to an edge.
+# ``ties`` lists (row, first, second) positions set to a shared maximum;
+# "edge" places them on the two sides of the first vocab-block boundary.
+CONF_CASES = {
+    "rows-28-block-32-ragged-vocab": ((4, 7), 1000, ()),
+    "one-lane-over-a-chunk": ((2, 3), 513, ()),
+    "rows-8-vocab-exact": ((8,), 1024, ()),
+    "rows-12-ragged-3-lanes": ((12,), 515, ()),
+    "row-edge-block-ragged-vocab-block": ((264,), 4200, ()),
+    "tie-across-vocab-block-edge": (
+        (264,), 16640, ((0, "edge", "edge"), (7, 3, 3 + LANES),
+                        (200, 5, 6), (263, "edge", "edge"))),
+}
 
 
-@pytest.mark.parametrize("shape,vocab", CONF_SHAPES)
+def _conf_logits(shape, vocab, ties, dtype):
+    rows = int(np.prod(shape))
+    rng = jax.random.PRNGKey(rows * 7919 + vocab)
+    x = np.array(5 * jax.random.normal(rng, (rows, vocab)), np.float32)
+    vb = tiling(rows, vocab, jnp.dtype(dtype).itemsize)[1]
+    for row, first, second in ties:
+        if first == "edge":
+            first, second = vb - 1, vb
+        x[row, [first, second]] = 40.0
+    return jnp.asarray(x.reshape(shape + (vocab,))).astype(dtype)
+
+
+@pytest.mark.parametrize("case", CONF_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_confidence_kernel_matches_ref(shape, vocab, dtype):
-    rng = jax.random.PRNGKey(hash((shape, vocab)) % 2**31)
-    logits = (5 * jax.random.normal(rng, shape + (vocab,))).astype(dtype)
+def test_confidence_kernel_matches_ref(case, dtype):
+    shape, vocab, ties = CONF_CASES[case]
+    logits = _conf_logits(shape, vocab, ties, dtype)
     a, p, m, e = confidence_fused(logits)
     ra, rp, rm, re = confidence_ref(logits)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(ra))
     np.testing.assert_allclose(p, rp, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(m, rm, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(e, re, rtol=2e-3, atol=2e-4)
+    flat_a, flat_m = np.asarray(a).reshape(-1), np.asarray(m).reshape(-1)
+    vb = tiling(flat_a.size, vocab, jnp.dtype(dtype).itemsize)[1]
+    for row, first, _ in ties:
+        # equal maxima: the lower index wins and the margin is exactly 0
+        assert flat_a[row] == (vb - 1 if first == "edge" else first)
+        assert flat_m[row] == 0.0
 
 
 def test_confidence_kernel_duplicate_max():
@@ -53,6 +78,26 @@ def test_confidence_kernel_extreme_logits():
     assert int(a[0]) == int(ra[0])
     np.testing.assert_allclose(p, rp, rtol=1e-5)
     assert np.isfinite(np.asarray(e)).all()
+
+
+@pytest.mark.parametrize("rows,vocab,itemsize", [
+    (1024, 126464, 4), (1024, 126464, 2), (8, 126464, 4), (12, 1000, 2),
+    (264, 65024, 4), (2048, 151936, 2), (512, 51865, 2), (3, 130, 4),
+    (1, 8, 4), (40, 2000, 2),
+])
+def test_confidence_tiling_rule(rows, vocab, itemsize):
+    rb, vb = tiling(rows, vocab, itemsize)
+    assert rb % 8 == 0 and vb % LANES == 0 and vb <= MAX_CHUNKS * LANES
+    assert rb <= -(-rows // 8) * 8           # never beyond the padded rows
+    assert vb <= -(-vocab // LANES) * LANES  # nor the padded vocabulary
+    assert vmem_bytes(rb, vb, itemsize) <= VMEM_LIMIT
+    if vocab == 126464:
+        # 128·988 has divisors near the block budget: no ragged block
+        assert vocab % vb == 0
+    if (rows, vocab, itemsize) == (1024, 126464, 4):
+        steps = -(-rows // rb) * -(-vocab // vb)
+        assert steps <= 300, steps
+        assert vocab % vb == 0
 
 
 ATTN_SHAPES = [

@@ -5,6 +5,8 @@ tests catch what interpret mode cannot: block shapes and layouts that
 Mosaic refuses.  The topology is described inside a fixture (never at
 import time) so that only the worker running this file loads libtpu.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -46,3 +48,29 @@ def test_confidence_kernel_compiles_for_v5e(one_chip, dtype, vocab):
     fn = jax.jit(lambda x: confidence_fused(x, interpret=False))
     text = fn.lower(logits).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_confidence_kernel_reads_the_cell_logits_in_place(one_chip):
+    """At the benchmark cell's shape the kernel's custom call takes the
+    2-D f32[1024,126464] logits the (4, 256) positions flatten to, with
+    no pad before it: the operand ``bench.trace_reduce.kernel_calls``
+    reads rows and vocabulary from."""
+    logits = jax.ShapeDtypeStruct((4, 256, LLADA_VOCAB), jnp.float32,
+                                  sharding=one_chip)
+    fn = jax.jit(lambda x: confidence_fused(x, interpret=False))
+    lowered = fn.lower(logits)
+    module = lowered.as_text()
+    assert "stablehlo.pad" not in module
+    assert re.search(r"tpu_custom_call.*\(tensor<1024x126464xf32>\)",
+                     module)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"\bpad\(", text)
+    lines = text.splitlines()
+    call = next(ln for ln in lines
+                if "tpu_custom_call" in ln and "custom-call(" in ln)
+    operand = re.search(r"custom-call\((%[\w.-]+)\)", call)[1]
+    defn = next(ln for ln in lines if ln.strip().startswith(operand + " "))
+    # a bitcast of the caller's logits (no copy): same bytes, 2-D view
+    assert re.search(r"= f32\[1024,126464\]\{[^}]*\} "
+                     r"(bitcast|parameter)\(", defn), defn
