@@ -13,10 +13,11 @@ the configuration's file by hand, with the bytes printed here.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import sys
+
+from bench import run
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # peak_bytes_in_use's limit on one v5e chip (bytes_limit, chip run PR 11)
@@ -41,14 +42,15 @@ def measure(config: dict, mixes: list, depth: int, one_chip) -> dict:
     import jax
     import jax.numpy as jnp
     from bench import weights
-    from repro.configs import DecodeConfig, get_config
+    from repro.configs import DecodeConfig
     from repro.core import Decoder
 
-    cfg = dataclasses.replace(get_config(config["repo_config"]),
-                              num_layers=depth)
-    sizes = config["sizes"]
-    flat = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
-            for k, s in weights.param_shapes(sizes, depth).items()}
+    family = run.load_family(config["family"])
+    cfg = family.program_config(dict(config, depth=depth))
+    flat = {k: jax.ShapeDtypeStruct(leaf.shape, jnp.bfloat16,
+                                    sharding=one_chip)
+            for k, leaf in family.param_shapes(config["sizes"],
+                                               depth).items()}
     params = weights.unflatten(flat)
     need = {}
     for mix in mixes:
@@ -86,9 +88,7 @@ def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one_chip = SingleDeviceSharding(topo.devices[0])
-    with open(os.path.join(ROOT, "bench", "configs",
-                           args.config + ".json")) as f:
-        config = json.load(f)
+    config = run.load_config(args.config)
     mixes = [traffic.load_mix(c["traffic"]) for c in cells_of(args.config)]
     for depth in range(args.max, args.min - 1, -1):
         need = measure(config, mixes, depth, one_chip)

@@ -1,16 +1,19 @@
 """Operations and bytes the cells' algorithms require, counted from shapes.
 
 A multiply-add is two operations.  Counts are per sequence (one row of a
-batch); callers multiply by the real rows served.  What is counted:
+batch); callers multiply by the real rows served.  The terms that
+depend on the architecture are the family's (``bench/families/``: a
+layer over given rows and keys, the head, the keys a row attends to, a
+cache refresh); here is the walk over the decode's geometry.  What is
+counted:
 
-* a forward over ``rows`` query rows and ``keys`` key rows, through every
-  layer: the Q/K/V/O projections, the attention scores and their
-  weighted sum over all keys, and the SwiGLU MLP;
+* a forward over ``rows`` query rows, each attending to the keys the
+  family gives, through every layer;
 * the LM head only over the rows the strategy reads: at each step the
   block's still-masked rows for the scoring forward, and every
   still-masked row of the canvas for each foreseeing candidate forward
   (its global confidence sums over them);
-* a cache refresh as one full-canvas forward without the head.
+* a cache refresh at each block's start, as the family costs it.
 
 So a program that computes the head over rows nobody reads, or
 recomputes rows a cache could have kept, executes more than is counted:
@@ -21,27 +24,14 @@ from __future__ import annotations
 from bench.reference import commit_widths
 
 
-def layer_flops(sizes: dict, rows: int, keys: int) -> int:
-    d, hd, ff = sizes["d_model"], sizes["head_dim"], sizes["d_ff"]
-    nq, nkv = sizes["num_heads"], sizes["num_kv_heads"]
-    proj = 2 * rows * d * (nq + 2 * nkv) * hd + 2 * rows * nq * hd * d
-    attn = 2 * 2 * rows * keys * nq * hd
-    mlp = 3 * 2 * rows * d * ff
-    return proj + attn + mlp
-
-
-def head_flops(sizes: dict, rows: int) -> int:
-    return 2 * rows * sizes["d_model"] * sizes["vocab_size"]
-
-
-def forward_flops(sizes: dict, depth: int, rows: int, keys: int,
+def forward_flops(family, sizes: dict, depth: int, rows: int, keys: int,
                   head_rows: int) -> int:
-    return depth * layer_flops(sizes, rows, keys) \
-        + head_flops(sizes, head_rows)
+    return depth * family.layer_flops(sizes, rows, keys) \
+        + family.head_flops(sizes, head_rows)
 
 
-def request_flops(sizes: dict, depth: int, geometry: dict, prompt_len: int,
-                  fwd_per_step: float) -> float:
+def request_flops(family, sizes: dict, depth: int, geometry: dict,
+                  prompt_len: int, fwd_per_step: float) -> float:
     """Operations one sequence's decode requires under the cell's
     geometry (``gen_length``, ``block_size``, ``steps``,
     ``cache_policy``).  ``fwd_per_step`` is the strategy's forwards per
@@ -54,19 +44,22 @@ def request_flops(sizes: dict, depth: int, geometry: dict, prompt_len: int,
     done = 0
     flops = 0.0
     for blk, widths in enumerate(commit_widths(gen, bs, geometry["steps"])):
+        lo = prompt_len + blk * bs
         if dual:
-            flops += depth * layer_flops(sizes, total, total)
+            flops += family.refresh_flops(sizes, depth, lo, bs, total)
+            rows, first = bs, lo                  # the block's window
+        else:
+            rows, first = total, 0                # the whole canvas
+        keys = family.keys(sizes, first, rows, total)
         in_block = 0
         for n in widths:
             m_blk = bs - in_block                 # block rows still masked
             m_all = gen - done                    # canvas rows still masked
-            if dual:
-                flops += forward_flops(sizes, depth, bs, total, m_blk)
-            else:
-                flops += forward_flops(sizes, depth, total, total, m_blk)
+            flops += forward_flops(family, sizes, depth, rows, keys, m_blk)
+            if not dual:
                 extra = fwd_per_step - 1.0
-                flops += extra * forward_flops(sizes, depth, total, total,
-                                               m_all)
+                flops += extra * forward_flops(family, sizes, depth, rows,
+                                               keys, m_all)
             in_block += n
             done += n
     return flops

@@ -15,7 +15,8 @@ from bench import flops, spans, trace_reduce
 def step_mfu(run):
     """Share of the chip's bf16 peak that the decode of each batch
     attains, in %: the operations the cell's algorithm requires for the
-    batch's real rows (``bench/flops.py``) over the batch's decode spans
+    batch's real rows (``bench/flops.py`` with the configuration's
+    family's terms) over the batch's decode spans
     times the peak."""
     bs = spans.batches(run.records)
     if not bs:
@@ -23,8 +24,8 @@ def step_mfu(run):
     sizes, depth = run.config["sizes"], run.config["depth"]
     dec = run.mix["decode"]
     work = sum(b.rows * flops.request_flops(
-        sizes, depth, dec, b.prompt_len, b.forward_equivalents / b.steps)
-        for b in bs)
+        run.family, sizes, depth, dec, b.prompt_len,
+        b.forward_equivalents / b.steps) for b in bs)
     seconds = sum(b.decode_s for b in bs)
     return 100.0 * work / (seconds * run.peaks["bf16_flops_per_s"])
 

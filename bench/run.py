@@ -4,9 +4,12 @@
 
 The cell (an entry of ``BENCHMARK.json``'s ``workloads``) names a
 configuration (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``); its limits are in
-``bench/limits/<cell>.json`` and its per-layer metrics are the readers
-``bench/metrics/<metric>.py`` that ``BENCHMARK.json`` lists for it.
+(``bench/traffic/<traffic>.json``); the configuration names its model
+family (``bench/families/<family>.py``: the program check, the weights'
+leaves, the operation count's terms and the plain reference); the
+cell's limits are in ``bench/limits/<cell>.json`` and its per-layer
+metrics are the readers ``bench/metrics/<metric>.py`` that
+``BENCHMARK.json`` lists for it.
 
 Set-up builds the weights on the device from the seed, warms up the
 programs the cell's shapes use, and starts the server; ``setup_s`` runs
@@ -15,8 +18,8 @@ window offers the mix's load for ``--seconds`` through the HTTP/SSE
 front end (``ServerThread`` -> ``AsyncScheduler`` -> ``ServingEngine``
 -> ``Decoder`` -> the model and the confidence kernel), timed from the
 client's side.  Then the peak device memory is read, the server and the
-weights are dropped, and the plain reference replays a seeded sample of
-the finished requests (``bench/reference.py``).
+weights are dropped, and the family's plain reference replays a seeded
+sample of the finished requests (``bench/reference.py``).
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
@@ -40,19 +43,40 @@ import tempfile
 import threading
 import time
 
+from bench.families import SetupError
+
 T_PROCESS = time.perf_counter()
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
 
-
-class SetupError(RuntimeError):
-    """The run cannot start: no accelerator, a missing file, or a program
-    whose shapes differ from the configuration's."""
+CONFIGS = os.path.join(BENCH, "configs")
+FAMILIES = os.path.join(BENCH, "families")
+_modules: dict = {}
 
 
 def _json(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def _module(path: str, name: str):
+    """The module of the file at ``path``, loaded once."""
+    if path not in _modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        _modules[path] = module
+    return _modules[path]
+
+
+def load_config(name: str) -> dict:
+    return _json(os.path.join(CONFIGS, name + ".json"))
+
+
+def load_family(name: str):
+    """The family module ``<FAMILIES>/<name>.py`` (bench/families/)."""
+    return _module(os.path.join(FAMILIES, name + ".py"),
+                   "bench_family_" + name)
 
 
 def load_cell(workload: str):
@@ -63,7 +87,7 @@ def load_cell(workload: str):
         raise SetupError(f"unknown workload {workload!r}; "
                          f"BENCHMARK.json has {sorted(cells)}")
     cell = cells[workload]
-    config = _json(os.path.join(BENCH, "configs", cell["config"] + ".json"))
+    config = load_config(cell["config"])
     mix = _json(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
     limits = _json(os.path.join(BENCH, "limits", workload + ".json"))
     return spec, cell, config, mix, limits
@@ -90,37 +114,14 @@ def per_layer_readers(spec: dict, workload: str) -> dict:
     for entry in spec["per_layer"]:
         if workload not in entry.get("workloads", [workload]):
             continue
-        path = os.path.join(BENCH, "metrics", entry["name"] + ".py")
-        mod_spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + entry["name"].replace(".", "_"), path)
-        module = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(module)
-        out[entry["name"]] = (entry, module.read)
+        name = entry["name"]
+        module = _module(os.path.join(BENCH, "metrics", name + ".py"),
+                         "bench_metric_" + name.replace(".", "_"))
+        out[name] = (entry, module.read)
     return out
 
 
 # -- the program --------------------------------------------------------------
-
-def model_config(config: dict):
-    """The program's ModelConfig at the configuration's depth, rotary
-    base and mask token; raises if its shapes are not the
-    configuration's."""
-    from repro.configs import get_config
-    s = config["sizes"]
-    cfg = dataclasses.replace(get_config(config["repo_config"]),
-                              num_layers=int(config["depth"]),
-                              rope_theta=float(s["rope_theta"]),
-                              mask_token_id=int(s["mask_token_id"]))
-    got = {"d_model": cfg.d_model, "num_heads": cfg.num_heads,
-           "num_kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
-           "d_ff": cfg.d_ff, "vocab_size": cfg.vocab_size, "rope": cfg.rope}
-    diff = {k: (v, s[k]) for k, v in got.items() if v != s[k]}
-    if diff or cfg.arch_type != "dense" or cfg.attention != "gqa" \
-            or cfg.qk_norm or cfg.act != "silu" or cfg.tie_embeddings:
-        raise SetupError(f"{config['repo_config']} is not the configured "
-                         f"dense GQA model: (program, file) {diff}")
-    return cfg
-
 
 def check_layout(params, cfg) -> None:
     """The seeded tree has exactly the program's parameter paths and
@@ -350,8 +351,8 @@ def sample(records, mix: dict, seed: int):
     return pick
 
 
-def check(records, config: dict, mix: dict, seed: int, limits: dict,
-          control: bool = False):
+def check(records, config: dict, family, mix: dict, seed: int,
+          limits: dict, control: bool = False):
     """Replay the sample with the reference.  Returns the compared
     numbers; with ``control`` the float8 control's numbers under the same
     limits (else None); and the widest gaps, program's and control's,
@@ -366,13 +367,14 @@ def check(records, config: dict, mix: dict, seed: int, limits: dict,
     picked = [r for r in sample(records, mix, seed)
               if not answer_faults(r, gen)]
     flat = weights.flatten(weights.make_params(
-        sizes, config["depth"], seed, jnp.dtype(config["weights_dtype"])))
+        family.param_shapes(sizes, config["depth"]), seed,
+        jnp.dtype(config["weights_dtype"])))
     gaps, cgaps = [np.zeros(0)], [np.zeros(0)]
     for lp in sorted({len(r.prompt) for r in picked}):
         group = [r for r in picked if len(r.prompt) == lp]
         prompts = np.stack([r.prompt for r in group])
         served = np.array([r.tokens[lp:] for r in group], np.int32)
-        g, cg = reference.replay(flat, sizes, prompts, served, dec,
+        g, cg = reference.replay(family, flat, sizes, prompts, served, dec,
                                  mask_id, control)
         gaps.append(g.ravel())
         cgaps.append(cg.ravel())
@@ -425,11 +427,13 @@ def run_cell(spec, workload: str, config: dict, mix: dict, limits: dict,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     dev = devices[0]
-    cfg = model_config(config)
+    family = load_family(config["family"])
+    cfg = family.program_config(config)
     dcfg = decode_config(mix)
     marks = {"start": time.perf_counter()}
-    params = weights.make_params(config["sizes"], config["depth"], seed,
-                                 jnp.dtype(config["weights_dtype"]))
+    params = weights.make_params(
+        family.param_shapes(config["sizes"], config["depth"]), seed,
+        jnp.dtype(config["weights_dtype"]))
     jax.block_until_ready(params)
     marks["weights"] = time.perf_counter()
     check_layout(params, cfg)
@@ -473,8 +477,8 @@ def run_cell(spec, workload: str, config: dict, mix: dict, limits: dict,
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         result["breakdown"] = reduced["breakdown"]
-        view = RunView(workload, config, mix, load.records, load.t0,
-                       load.t1, reduced, device_peaks(dev))
+        view = RunView(workload, config, family, mix, load.records,
+                       load.t0, load.t1, reduced, device_peaks(dev))
         for name, (entry, read) in per_layer_readers(spec,
                                                      workload).items():
             value = read(view)
@@ -489,8 +493,8 @@ def run_cell(spec, workload: str, config: dict, mix: dict, limits: dict,
     gc.collect()
     jax.clear_caches()
     t_ref = time.perf_counter()
-    compared, ctrl, gaps = check(load.records, config, mix, seed, limits,
-                                 control)
+    compared, ctrl, gaps = check(load.records, config, family, mix, seed,
+                                 limits, control)
     diag.update(gaps)
     diag["setup_parts_s"] = {k: marks[k] - marks[p] for p, k in
                              zip(list(marks), list(marks)[1:])}
@@ -508,6 +512,7 @@ class RunView:
     """What a per-layer metric reader sees of a traced run."""
     workload: str
     config: dict
+    family: object      # the configuration's bench/families/ module
     mix: dict
     records: list
     t0: float

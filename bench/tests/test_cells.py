@@ -20,17 +20,16 @@ def test_cell_files_parse(cell):
     spec, entry, config, mix, limits = run.load_cell(cell)
     assert entry["chips"] == 1
     assert config["name"] == entry["config"]
-    assert set(config["sizes"]) >= {"d_model", "num_heads", "num_kv_heads",
-                                    "head_dim", "d_ff", "vocab_size",
-                                    "rope", "rope_theta", "norm_eps",
-                                    "mask_token_id"}
+    family = run.load_family(config["family"])
+    assert "mask_token_id" in config["sizes"]
     assert mix["arrivals"] in ("backlog", "poisson")
     assert abs(sum(mix["prompt_lengths"].values()) - 1.0) < 1e-9
     assert mix["decode"]["gen_length"] % mix["decode"]["block_size"] == 0
     assert 0 < limits["mismatch_share"] < 1
     # the configuration's program exists with the configured shapes
-    cfg = run.model_config(config)
+    cfg = family.program_config(config)
     assert cfg.num_layers == config["depth"]
+    assert family.param_shapes(config["sizes"], config["depth"])
 
 
 def test_every_per_layer_metric_has_a_reader():

@@ -45,15 +45,17 @@ def cell_files(cell: str):
     return config, SERVE_MIX if cell == SERVE else mix, limits
 
 
-def tiny(cell: str):
-    """The cell's configuration and mix cut to the repo's tiny preset of
-    the same model and a few short requests."""
-    config, mix, limits = cell_files(cell)
+def tiny_config(config: dict) -> dict:
+    """A configuration at the program's tiny preset of the same model:
+    two layers and the family's widths of the preset."""
     t = get_config(config["repo_config"] + "-tiny")
-    config = dict(config, repo_config=t.name, depth=2, sizes=dict(
-        config["sizes"], d_model=t.d_model, num_heads=t.num_heads,
-        num_kv_heads=t.num_kv_heads, head_dim=t.head_dim, d_ff=t.d_ff,
-        vocab_size=t.vocab_size, mask_token_id=t.mask_token_id))
+    family = run.load_family(config["family"])
+    return dict(config, repo_config=t.name, depth=2,
+                sizes=family.tiny_sizes(config["sizes"], t))
+
+
+def tiny_mix(mix: dict) -> dict:
+    """A mix cut to a few short requests."""
     mix = json.loads(json.dumps(mix))
     mix["decode"].update(gen_length=16, block_size=8,
                          steps=mix["decode"]["steps"] // 8)
@@ -62,7 +64,14 @@ def tiny(cell: str):
         [str(16 + 8 * i) for i in range(len(lengths))],
         mix["prompt_lengths"].values()))
     mix.update(max_batch=2, backlog=4, rate_per_s=3.0)
-    return config, mix, limits
+    return mix
+
+
+def tiny(cell: str):
+    """The cell's configuration and mix cut to the repo's tiny preset of
+    the same model and a few short requests."""
+    config, mix, limits = cell_files(cell)
+    return tiny_config(config), tiny_mix(mix), limits
 
 
 def mix_of(cell: str) -> dict:

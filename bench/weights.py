@@ -1,28 +1,31 @@
-"""Seeded random weights of a dense GQA model, in the served dtype.
+"""Seeded random weights, in the served dtype, of any family's tree.
 
 The tree is the layout the served model reads (``embed``, ``norm_f`` and
-one stacked group in ``blocks``); ``bench.run`` checks it against the
-program's own parameter shapes before serving.  Scales follow the usual
-fan-in rule: 0.02 for the token embedding, ``1/sqrt(fan_in)`` for every
-projection and the LM head, ones for the norm scales.
+one stacked group in ``blocks``); the family (``bench/families/``) gives
+each leaf's path, shape and scale, and ``bench.run`` checks the tree
+against the program's own parameter shapes before serving.
 
 Every leaf comes from its own key, folded from the seed and the leaf's
-name, and a stacked leaf is drawn one layer at a time inside one jitted
-call, so the random bits of a single layer are the largest temporary.
-The same seed always gives the same weights, whichever process asks.
+path, and a stacked leaf (``blocks/...``, a leading layer axis) is drawn
+one layer at a time inside one jitted call, so the random bits of a
+single layer are the largest temporary.  The same seed always gives the
+same weights, whichever process asks.
 """
 from __future__ import annotations
 
 import zlib
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-# (leaf path, shape kind); a "stack" leaf has a leading layer axis
-_MATRICES = (("attn/wq", "d,q"), ("attn/wk", "d,kv"), ("attn/wv", "d,kv"),
-             ("attn/wo", "q,d"), ("mlp/gate", "d,ff"), ("mlp/up", "d,ff"),
-             ("mlp/down", "ff,d"))
+
+class Leaf(NamedTuple):
+    """A leaf's shape and how it is drawn: normal with standard deviation
+    ``std``, or ones where ``std`` is None."""
+    shape: tuple
+    std: Optional[float]
 
 
 def seed_key(seed: int, tag: str) -> jax.Array:
@@ -33,57 +36,34 @@ def seed_key(seed: int, tag: str) -> jax.Array:
     return jax.random.PRNGKey(int(words[0]) & 0x7FFFFFFF)
 
 
-def _dims(sizes: dict) -> dict:
-    hd = sizes["head_dim"]
-    return {"d": sizes["d_model"], "q": sizes["num_heads"] * hd,
-            "kv": sizes["num_kv_heads"] * hd, "ff": sizes["d_ff"],
-            "V": sizes["vocab_size"]}
-
-
-def param_shapes(sizes: dict, depth: int) -> dict:
-    """{leaf path: shape} of the tree ``make_params`` builds."""
-    dims = _dims(sizes)
-    shapes = {"embed/tok": (dims["V"], dims["d"]),
-              "embed/head": (dims["d"], dims["V"]),
-              "norm_f/scale": (dims["d"],),
-              "blocks/norm1/scale": (depth, dims["d"]),
-              "blocks/norm2/scale": (depth, dims["d"])}
-    for path, kind in _MATRICES:
-        rows, cols = kind.split(",")
-        shapes["blocks/" + path] = (depth, dims[rows], dims[cols])
-    return shapes
-
-
 def _leaf(key, shape, std, dtype):
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
 def _stacked(key, shape, std, dtype):
-    """(depth, rows, cols): layer i from fold_in(key, i), one layer of
-    random bits alive at a time."""
+    """(depth, ...): layer i from fold_in(key, i), one layer of random
+    bits alive at a time."""
     def body(i, buf):
         return buf.at[i].set(_leaf(jax.random.fold_in(key, i), shape[1:],
                                    std, dtype))
     return jax.lax.fori_loop(0, shape[0], body, jnp.zeros(shape, dtype))
 
 
-def make_params(sizes: dict, depth: int, seed: int, dtype=jnp.bfloat16):
-    """Build the whole tree on the default device in one jitted call."""
-    shapes = param_shapes(sizes, depth)
+def make_params(shapes: dict, seed: int, dtype=jnp.bfloat16):
+    """Build the tree of ``shapes`` ({path: Leaf}, a family's
+    ``param_shapes``) on the default device in one jitted call."""
     key = seed_key(seed, "weights")
 
     def build(key):
         flat = {}
-        for path, shape in shapes.items():
+        for path, leaf in shapes.items():
             k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
-            if path.endswith("/scale"):
-                flat[path] = jnp.ones(shape, dtype)
-            elif path == "embed/tok":
-                flat[path] = _leaf(k, shape, 0.02, dtype)
-            elif path == "embed/head":
-                flat[path] = _leaf(k, shape, shape[0] ** -0.5, dtype)
+            if leaf.std is None:
+                flat[path] = jnp.ones(leaf.shape, dtype)
+            elif path.startswith("blocks/"):
+                flat[path] = _stacked(k, leaf.shape, leaf.std, dtype)
             else:
-                flat[path] = _stacked(k, shape, shape[1] ** -0.5, dtype)
+                flat[path] = _leaf(k, leaf.shape, leaf.std, dtype)
         return flat
 
     flat = jax.jit(build)(key)
