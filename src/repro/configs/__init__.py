@@ -23,17 +23,25 @@ _MODULES: Dict[str, str] = {
     "hymba-1.5b":       "repro.configs.hymba_1_5b",
     "qwen2-vl-72b":     "repro.configs.qwen2_vl_72b",
     "llada-8b":         "repro.configs.llada_8b",
+    "sdar-30b-a3b":     "repro.configs.sdar_30b_a3b",
 }
 
-ASSIGNED_ARCHS: List[str] = [k for k in _MODULES if k != "llada-8b"]
+# the pool the dry-run and the per-arch smoke tests cover; the diffusion
+# LMs the decoders serve are not in it
+ASSIGNED_ARCHS: List[str] = [k for k in _MODULES
+                             if k not in ("llada-8b", "sdar-30b-a3b")]
 
 
 def get_config(name: str) -> ModelConfig:
-    if name.endswith("-tiny"):
-        return get_config(name[: -len("-tiny")]).reduced()
-    if name not in _MODULES:
+    """A config by arch id; ``<id>-tiny`` is its CPU test preset: the
+    module's ``TINY`` where it has one, else ``CONFIG.reduced()``."""
+    base = name[: -len("-tiny")] if name.endswith("-tiny") else name
+    if base not in _MODULES:
         raise KeyError(f"unknown arch {name!r}; available: {sorted(_MODULES)}")
-    return importlib.import_module(_MODULES[name]).CONFIG
+    module = importlib.import_module(_MODULES[base])
+    if base == name:
+        return module.CONFIG
+    return getattr(module, "TINY", None) or module.CONFIG.reduced()
 
 
 def list_configs() -> List[str]:

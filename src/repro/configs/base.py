@@ -19,6 +19,16 @@ class MoEConfig:
     moe_d_ff: int = 0                 # per-expert hidden size
     first_k_dense: int = 0            # leading layers that stay dense (DeepSeek-V2: 1)
     router_aux_coef: float = 0.01     # load-balance loss weight
+    # the routed experts this device holds: ids held_first ..
+    # held_first + held_count - 1 (0 = all).  The router keeps its full
+    # width; the layer computes only the held experts' part of the
+    # result (one chip's share under expert parallelism)
+    held_first: int = 0
+    held_count: int = 0
+
+    @property
+    def num_held(self) -> int:
+        return self.held_count or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -70,6 +80,10 @@ class ModelConfig:
     mrope_sections: Tuple[int, ...] = ()   # M-RoPE split of head_dim/2 (t, h, w)
     qk_norm: bool = False
     sliding_window: int = 0           # 0 = full attention
+    attn_block: int = 0               # block-causal attention: position i
+                                      # sees j iff j // attn_block <=
+                                      # i // attn_block (block-diffusion
+                                      # LMs); 0 = bidirectional
     norm: str = "rmsnorm"             # rmsnorm | layernorm
     act: str = "silu"                 # silu (SwiGLU) | gelu (plain MLP)
     tie_embeddings: bool = False

@@ -74,6 +74,7 @@ from repro.core.loop import (carry_unwindow, carry_window,
 from repro.core.masking import fully_masked
 from repro.core.strategies import Strategy, resolve_strategy
 from repro.core.tracebuffer import DecodeTrace, TracingStrategy, tracing
+from repro.models.moe import expert_counter
 
 
 @dataclass
@@ -97,6 +98,15 @@ class SampleStats:
     # committed straight from the carry.  Plain path invariant:
     # steps == forward_equivalents + skipped_forwards (the cached path
     # pro-rates forwards by window size but counts skips raw).
+    expert_rows: float = 0.0
+    # MoE models: the (token, held expert) pairs the dropless expert
+    # layers computed (models/moe.py), summed over the decode; whole-
+    # batch totals like forwards, pro-rated per request by ServingEngine
+    experts_read: float = 0.0
+    # held experts that got at least one row, summed over expert-layer
+    # calls
+    expert_calls: float = 0.0
+    # expert-layer calls: MoE layers × forwards (refreshes included)
     trace: Optional[DecodeTrace] = None
     # per-step telemetry (dcfg.trace=True only): commit order/confidence,
     # revocations, skips, phases — core/tracebuffer.py.
@@ -125,6 +135,9 @@ class SampleStats:
             "tokens_per_forward": float(self.tokens_per_forward),
             "revocations": float(self.revocations),
             "skipped_forwards": float(self.skipped_forwards),
+            "expert_rows": float(self.expert_rows),
+            "experts_read": float(self.experts_read),
+            "expert_calls": float(self.expert_calls),
             "phase_counts": dict(self.phase_counts),
         }
 
@@ -297,6 +310,26 @@ def _tiling_forward(params, cfg: ModelConfig, extras: Dict[str, Any]):
     return mf
 
 
+def _counted(cfg: ModelConfig, fn: Callable) -> Tuple[Any, Any]:
+    """``(fn(), counts)`` inside a runner's trace: ``counts`` is the
+    expert counters the trace of ``fn`` added up for an MoE model
+    (``models.moe.expert_counter``), None for any other."""
+    with expert_counter(cfg) as read:
+        out = fn()
+        return out, read()
+
+
+def _merge_expert_counts(stats: "SampleStats", tally: list) -> None:
+    """Sum a decode's expert counters (device arrays, None for models
+    without experts) into ``stats`` with one transfer."""
+    tally = [c for c in tally if c is not None]
+    if tally:
+        rows, read, calls = np.sum(jax.device_get(tally), axis=0)
+        stats.expert_rows = float(rows)
+        stats.experts_read = float(read)
+        stats.expert_calls = float(calls)
+
+
 def _tile_state(st, reps: int):
     """Replicate a DecodeState candidate-major along its batch axis."""
     if reps == 1:
@@ -320,6 +353,27 @@ def _cached_model_fn(params, cfg: ModelConfig, batch: int) -> Callable:
                               _tile_state(st, w.shape[0] // batch), cfg)
 
     return cf
+
+
+def validate_block_causal(cfg: ModelConfig, dcfg: DecodeConfig,
+                          prompt_len: Optional[int] = None) -> None:
+    """Boundary validation for a block-causal model (``cfg.attn_block``
+    > 0): it decodes in its own attention blocks — ``block_size`` equal
+    to the block length, and a prompt (where known) a whole number of
+    blocks long, so that every decode block is one attention block.
+    Raises ``ValueError`` otherwise (a 400 at ``ServingEngine.submit``).
+    """
+    blk = cfg.attn_block
+    if not blk:
+        return
+    if dcfg.block_size != blk:
+        raise ValueError(
+            f"{cfg.name!r} attends block-causally in blocks of {blk}: "
+            f"decode it with block_size={blk}, not {dcfg.block_size}")
+    if prompt_len is not None and prompt_len % blk:
+        raise ValueError(
+            f"{cfg.name!r} attends block-causally in blocks of {blk}: "
+            f"the prompt length {prompt_len} is not a multiple of {blk}")
 
 
 def validate_cache_policy(cfg: ModelConfig, dcfg: DecodeConfig) -> None:
@@ -434,8 +488,9 @@ class Decoder:
                       extras: Optional[Dict[str, Any]] = None
                       ) -> Tuple[Callable, tuple]:
         """Per-block fused runner ``(run, lead)``, called as
-        ``run(*lead, x, rng, lo, sched, steps, fwd, carry) -> 5-tuple``
-        (``lead`` is the weights and extras in params mode); ``lo``
+        ``run(*lead, x, rng, lo, sched, steps, fwd, carry) -> (5-tuple,
+        expert counts)`` (``lead`` is the weights and extras in params
+        mode; the counts are None for a model without experts); ``lo``
         (block start) and ``sched`` (per-step commit widths) are traced,
         so all blocks (and all later decodes with the same weights) share
         one executable per shape."""
@@ -458,8 +513,9 @@ class Decoder:
                         raise RuntimeError("model_fn was garbage-collected")
                     pos = jnp.arange(x.shape[1])
                     in_block = (pos >= lo) & (pos < lo + bs)
-                    return drive_block(strat, mf, cfg, dcfg, sched,
-                                       x, rng, in_block, steps, fwd, carry)
+                    return _counted(cfg, lambda: drive_block(
+                        strat, mf, cfg, dcfg, sched, x, rng, in_block,
+                        steps, fwd, carry))
                 return run
 
             return cache.get(self._key, self._anchor, subkey, build), ()
@@ -471,8 +527,9 @@ class Decoder:
                 pos = jnp.arange(x.shape[1])
                 in_block = (pos >= lo) & (pos < lo + bs)
                 mf = _tiling_forward(params, cfg, ex)
-                return drive_block(strat, mf, cfg, dcfg, sched,
-                                   x, rng, in_block, steps, fwd, carry)
+                return _counted(cfg, lambda: drive_block(
+                    strat, mf, cfg, dcfg, sched, x, rng, in_block, steps,
+                    fwd, carry))
             return run
 
         raw = self._cache.get(self._key, self._anchor, subkey, build)
@@ -486,7 +543,8 @@ class Decoder:
         ``run(x, rng, block_los, schedules, steps, fwd, carry)`` with the
         block offsets and commit schedules traced, so one executable per
         strategy × shape serves every prompt length / step budget of that
-        shape.
+        shape; it returns the 5-tuple and the expert counts, as the
+        per-block runner does.
 
         Streaming: compiled programs outlive any single ``generate`` call,
         so the per-call ``on_block_committed`` cannot be baked in.  The
@@ -523,9 +581,9 @@ class Decoder:
                     mf = mf_ref()
                     if mf is None:
                         raise RuntimeError("model_fn was garbage-collected")
-                    return drive_request(strat, mf, cfg, dcfg, x, rng,
-                                         los, scheds, steps, fwd, carry,
-                                         emit=emit)
+                    return _counted(cfg, lambda: drive_request(
+                        strat, mf, cfg, dcfg, x, rng, los, scheds, steps,
+                        fwd, carry, emit=emit))
                 return run, holder
 
             return cache.get(self._key, self._anchor, subkey, build)
@@ -538,9 +596,9 @@ class Decoder:
             def run(params, ex, x, rng, los, scheds, steps, fwd, carry):
                 cache.note_trace()
                 mf = _tiling_forward(params, cfg, ex)
-                return drive_request(strat, mf, cfg, dcfg, x, rng,
-                                     los, scheds, steps, fwd, carry,
-                                     emit=emit)
+                return _counted(cfg, lambda: drive_request(
+                    strat, mf, cfg, dcfg, x, rng, los, scheds, steps, fwd,
+                    carry, emit=emit))
             return run, holder
 
         raw, holder = self._cache.get(self._key, self._anchor, subkey,
@@ -550,8 +608,10 @@ class Decoder:
                 raw(params, ex, x, rng, los, scheds, steps, fwd, carry),
                 holder)
 
-    def _host_model_fn(self, extras: Optional[Dict[str, Any]]) -> Callable:
-        """tokens -> logits for the legacy host step loop."""
+    def _host_model_fn(self, extras: Optional[Dict[str, Any]],
+                       tally: list) -> Callable:
+        """tokens -> logits for the legacy host step loop; each call's
+        expert counts go to ``tally``."""
         if self._model_fn is not None:
             if extras:
                 raise ValueError("extras require a params-mode Decoder")
@@ -562,18 +622,24 @@ class Decoder:
             @jax.jit
             def fwd(params, ex, t):
                 cache.note_trace()
-                return _tiling_forward(params, cfg, ex)(t)
+                return _counted(cfg,
+                                lambda: _tiling_forward(params, cfg, ex)(t))
             return fwd
 
         raw = cache.get(self._key, self._anchor, ("fwd", cfg), build)
         params, ex = self._params, dict(extras or {})
-        return lambda t: raw(params, ex, t)
+
+        def mf(t):
+            logits, counts = raw(params, ex, t)
+            tally.append(counts)
+            return logits
+        return mf
 
     def _refresh_runner(self) -> Tuple[Callable, tuple]:
         """Jitted cache capture ``(refresh, lead)``, called as
-        ``refresh(*lead, canvas) -> DecodeState`` — the prefill and
-        block-boundary refresh op of the cached path (one full forward
-        over the canvas, LM head skipped).  Strategy- and
+        ``refresh(*lead, canvas) -> (DecodeState, expert counts)`` — the
+        prefill and block-boundary refresh op of the cached path (one
+        full forward over the canvas, LM head skipped).  Strategy- and
         dcfg-independent: every policy and strategy on the same weights
         shares one compilation per canvas shape."""
         cfg, cache = self.cfg, self._cache
@@ -584,15 +650,17 @@ class Decoder:
             @jax.jit
             def refresh(params, canvas):
                 cache.note_trace()
-                return capture_cache(params, canvas, cfg)
+                return _counted(cfg,
+                                lambda: capture_cache(params, canvas, cfg))
             return refresh
 
         raw = cache.get(self._key, self._anchor, ("refresh", cfg), build)
         return raw, (self._params,)
 
-    def _cached_forward_fn(self) -> Callable:
+    def _cached_forward_fn(self, tally: list) -> Callable:
         """Jitted windowed forward ``(x_win, win_lo, state) -> logits``
-        for the host step loop of the cached path."""
+        for the host step loop of the cached path; each call's expert
+        counts go to ``tally``."""
         cfg, cache = self.cfg, self._cache
 
         def build():
@@ -601,13 +669,19 @@ class Decoder:
             @jax.jit
             def cfwd(params, w, win_lo, st):
                 cache.note_trace()
-                return forward_cached(params, w, win_lo, st, cfg)
+                return _counted(cfg, lambda: forward_cached(
+                    params, w, win_lo, st, cfg))
             return cfwd
 
         raw = cache.get(self._key, self._anchor, ("cached_fwd", cfg),
                         build)
         params = self._params
-        return lambda w, win_lo, st: raw(params, w, win_lo, st)
+
+        def cf(w, win_lo, st):
+            logits, counts = raw(params, w, win_lo, st)
+            tally.append(counts)
+            return logits
+        return cf
 
     def _cached_block_runner(self, strat: Strategy
                              ) -> Tuple[Callable, tuple]:
@@ -626,9 +700,9 @@ class Decoder:
             def run(params, x, rng, lo, sched, steps, fwd, carry, state):
                 cache.note_trace()
                 cf = _cached_model_fn(params, cfg, x.shape[0])
-                return drive_cached_block(strat, cf, cfg, dcfg, x, rng,
-                                          lo, sched, steps, fwd, carry,
-                                          state)
+                return _counted(cfg, lambda: drive_cached_block(
+                    strat, cf, cfg, dcfg, x, rng, lo, sched, steps, fwd,
+                    carry, state))
             return run
 
         raw = cache.get(self._key, self._anchor, subkey, build)
@@ -660,10 +734,10 @@ class Decoder:
                 cache.note_trace()
                 from repro.models.model import capture_cache
                 cf = _cached_model_fn(params, cfg, x.shape[0])
-                return drive_request_cached(
+                return _counted(cfg, lambda: drive_request_cached(
                     strat, cf, lambda cv: capture_cache(params, cv, cfg),
                     cfg, dcfg, x, rng, los, scheds, steps, fwd, carry,
-                    emit=emit)
+                    emit=emit))
             return run, holder
 
         raw, holder = self._cache.get(self._key, self._anchor, subkey,
@@ -717,6 +791,7 @@ class Decoder:
             # (per the dcfg-keyed subkeys) without recompiling per call
             # — and trace=off decodes never see the wrapper at all
             strat = tracing(strat)
+        validate_block_causal(cfg, dcfg, prompt.shape[1])
         cached = dcfg.cache_policy != "none"
         if cached:
             self._check_cached(extras)
@@ -755,7 +830,7 @@ class Decoder:
             holder["cb"] = on_block_committed
         try:
             los = lp + bs * jnp.arange(num_blocks, dtype=jnp.int32)
-            x, rng, steps, fwd, carry = run(
+            (x, rng, steps, fwd, carry), counts = run(
                 x, rng, los, jnp.asarray(sched),
                 jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32),
                 carry)
@@ -772,6 +847,7 @@ class Decoder:
                 holder["cb"] = None
         stats.steps = int(jax.device_get(steps))
         stats.forward_equivalents = float(jax.device_get(fwd))
+        _merge_expert_counts(stats, [counts])
         if isinstance(strat, TracingStrategy):
             stats.trace = strat.extract(carry)
         self._merge_carry_stats(stats, strat, carry)
@@ -806,6 +882,7 @@ class Decoder:
             strat = tracing(strat)
         # geometry errors should raise HERE, not at the first next()
         geometry = self._geometry()
+        validate_block_causal(self.cfg, self.dcfg, prompt.shape[1])
         return self._blocks_gen(strat, rng, prompt, geometry, extras)
 
     def lower_blocks(self, batch: int, prompt_len: int, **extras
@@ -829,6 +906,7 @@ class Decoder:
                 f"lower_blocks lowers the fused block runner, but "
                 f"{strat.name!r} with fused_loop={dcfg.fused_loop} "
                 f"decodes on the host step loop")
+        validate_block_causal(self.cfg, dcfg, prompt_len)
         cached = dcfg.cache_policy != "none"
         if cached:
             self._check_cached(extras)
@@ -841,7 +919,7 @@ class Decoder:
         if cached:
             refresh, rlead = self._refresh_runner()
             lowered["refresh"] = refresh.lower(*rlead, x)
-            state = jax.eval_shape(refresh, *rlead, x)
+            state, _ = jax.eval_shape(refresh, *rlead, x)
         run, lead = self._cached_block_runner(strat) if cached \
             else self._plain_runner(strat, extras)
         args = jax.eval_shape(self._block_args, x, rng, prompt_len,
@@ -881,16 +959,21 @@ class Decoder:
         # three drivers report the same forward_equivalents.
         refresh, rlead = self._refresh_runner() if cached else (None, ())
         hook = self.on_span
+        tally: list = []                 # expert counts, one per dispatch
 
         def timed_refresh(canvas, blk):
             if hook is None:
-                return refresh(*rlead, canvas)
-            # hook installed = serving-layer tracing: the extra sync is
-            # paid only then, and the blockwise caller syncs per block
-            # anyway (it materializes each block's tokens on host)
-            with hook(f"cache_refresh[{blk}]", "decode", {"block": blk}):
-                st = refresh(*rlead, canvas)
-                jax.block_until_ready(st)
+                st, counts = refresh(*rlead, canvas)
+            else:
+                # hook installed = serving-layer tracing: the extra sync
+                # is paid only then, and the blockwise caller syncs per
+                # block anyway (it materializes each block's tokens on
+                # host)
+                with hook(f"cache_refresh[{blk}]", "decode",
+                          {"block": blk}):
+                    st, counts = refresh(*rlead, canvas)
+                    jax.block_until_ready(st)
+            tally.append(counts)
             return st
 
         state = timed_refresh(x, 0) if cached else None
@@ -904,8 +987,10 @@ class Decoder:
                 if cached and blk > 0 and dcfg.cache_refresh == "block":
                     state = timed_refresh(x, blk)
                     refresh_fwd += 1.0
-                x, rng, steps, fwd, carry = run(*lead, *self._block_args(
-                    x, rng, lo, sched[blk], steps, fwd, carry, state))
+                (x, rng, steps, fwd, carry), counts = run(
+                    *lead, *self._block_args(x, rng, lo, sched[blk], steps,
+                                             fwd, carry, state))
+                tally.append(counts)
                 yield BlockEvent(blk, lo, lo + bs, x)
             # one sync for the whole decode: canvas + both stats counters
             x.block_until_ready()
@@ -913,8 +998,8 @@ class Decoder:
             stats.forward_equivalents = float(jax.device_get(fwd)) \
                 + refresh_fwd
         else:
-            cfwd = self._cached_forward_fn() if cached \
-                else self._host_model_fn(extras)
+            cfwd = self._cached_forward_fn(tally) if cached \
+                else self._host_model_fn(extras, tally)
             win, static_lo = window_geometry(dcfg, total) if cached \
                 else (total, 0)
             last = sched.shape[1] - 1
@@ -962,6 +1047,7 @@ class Decoder:
                 yield BlockEvent(blk, lo, hi, x)
             x.block_until_ready()
             stats.forward_equivalents += refresh_fwd
+        _merge_expert_counts(stats, tally)
         if isinstance(strat, TracingStrategy):
             stats.trace = strat.extract(carry)
         self._merge_carry_stats(stats, strat, carry)

@@ -15,8 +15,10 @@ single-token decode path:
   directly onto the TPU memory hierarchy (the latent cache stays in HBM, the
   absorbed weight products live in VMEM-resident tiles).
 
-Everything is bidirectional: LLDMs score all masked positions at once, so no
-causal mask ever appears here.
+LLDMs score all masked positions at once, so attention is bidirectional,
+except in block-diffusion LMs (SDAR): there ``cfg.attn_block`` > 0 and a
+position sees its own block and the blocks before it (``block_mask``), in
+the full path and in both ops of the fixed-shape block cache.
 """
 from __future__ import annotations
 
@@ -88,23 +90,43 @@ def band_mask(q_pos: jnp.ndarray, kv_pos: jnp.ndarray, window: int) -> jnp.ndarr
     return jnp.abs(diff) < window
 
 
+def block_mask(q_pos: jnp.ndarray, kv_pos: jnp.ndarray, block: int
+               ) -> jnp.ndarray:
+    """Block-causal: attend iff j // block <= i // block, blocks counted
+    from position 0."""
+    return kv_pos[..., None, :] // block <= q_pos[..., :, None] // block
+
+
+def position_mask(q_pos: jnp.ndarray, kv_pos: jnp.ndarray, window: int = 0,
+                  block: int = 0) -> Optional[jnp.ndarray]:
+    """The band and block-causal masks of absolute positions, combined;
+    None where attention is unmasked."""
+    mask = None
+    if window:
+        mask = band_mask(q_pos, kv_pos, window)
+    if block:
+        bm = block_mask(q_pos, kv_pos, block)
+        mask = bm if mask is None else mask & bm
+    return mask
+
+
 SDPA_CHUNK = 1024   # q-chunk for the memory-efficient long-sequence path
 
 
 def self_attention(q, k, v, scale: float, window: int = 0,
-                   chunk: int = SDPA_CHUNK) -> jnp.ndarray:
-    """Full bidirectional self-attention without materializing (L, L).
+                   chunk: int = SDPA_CHUNK, block: int = 0) -> jnp.ndarray:
+    """Full self-attention without materializing (L, L).
 
     Short sequences take the dense path; long ones scan q in chunks of
     ``chunk`` so the live score tensor is (B, H, chunk, L) — the
     memory-efficient jnp equivalent of the Pallas flash kernel (which
-    serves the same role on real TPU hardware).  Band masking is computed
-    per chunk from index arithmetic, never as an (L, L) bool.
+    serves the same role on real TPU hardware).  Band and block-causal
+    masks are computed per chunk from index arithmetic, never as an
+    (L, L) bool.
     """
     b, l, h, dh = q.shape
     if l <= chunk:
-        mask = band_mask(jnp.arange(l), jnp.arange(l), window) if window \
-            else None
+        mask = position_mask(jnp.arange(l), jnp.arange(l), window, block)
         return _sdpa(q, k, v, mask, scale)
     pad = (-l) % chunk
     if pad:
@@ -115,10 +137,8 @@ def self_attention(q, k, v, scale: float, window: int = 0,
 
     def body(_, xs):
         qch, start = xs
-        mask = None
-        if window:
-            qpos = start + jnp.arange(chunk)
-            mask = band_mask(qpos, kpos, window)          # (C, L) only
+        mask = position_mask(start + jnp.arange(chunk), kpos, window,
+                             block)                       # (C, L) only
         return None, _sdpa(qch, k, v, mask, scale)
 
     starts = jnp.arange(nch, dtype=jnp.int32) * chunk
@@ -183,10 +203,10 @@ def _project_qkv(p: Params, x, positions, cfg: ModelConfig):
 
 def gqa_forward(p: Params, x, positions, cfg: ModelConfig,
                 attn_mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Full bidirectional attention over x (B, L, d)."""
+    """Full attention over x (B, L, d)."""
     q, k, v = _project_qkv(p, x, positions, cfg)
     out = self_attention(q, k, v, cfg.head_dim ** -0.5,
-                         window=cfg.sliding_window)
+                         window=cfg.sliding_window, block=cfg.attn_block)
     out = constrain(out.reshape(*x.shape[:2], -1), ("dp", None, "tp"))
     # NOTE (§Perf C3, refuted & reverted): forcing the row-parallel product
     # to the sequence-parallel layout here (reduce-scatter instead of
@@ -308,7 +328,7 @@ def gqa_capture(p: Params, x, positions, cfg: ModelConfig
     prefill/refresh op of the fixed-shape block cache."""
     q, k, v = _project_qkv(p, x, positions, cfg)
     out = self_attention(q, k, v, cfg.head_dim ** -0.5,
-                         window=cfg.sliding_window)
+                         window=cfg.sliding_window, block=cfg.attn_block)
     out = constrain(out.reshape(*x.shape[:2], -1), ("dp", None, "tp"))
     # length is an array so the cache stacks/slices cleanly across the
     # per-group layer axis (it is never consulted: the cache is always full)
@@ -328,10 +348,9 @@ def gqa_cached(p: Params, x, positions, cfg: ModelConfig, cache: KVCache,
                                             win_start, axis=1)
     v = jax.lax.dynamic_update_slice_in_dim(cache.v.astype(dt), v_new,
                                             win_start, axis=1)
-    mask = None
-    if cfg.sliding_window:
-        mask = band_mask(win_start + jnp.arange(w),
-                         jnp.arange(cache.k.shape[1]), cfg.sliding_window)
+    mask = position_mask(win_start + jnp.arange(w),
+                         jnp.arange(cache.k.shape[1]), cfg.sliding_window,
+                         cfg.attn_block)
     out = _sdpa(q, k, v, mask, cfg.head_dim ** -0.5)
     out = out.reshape(*x.shape[:2], -1)
     return out @ p["wo"].astype(dt)

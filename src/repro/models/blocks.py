@@ -1,7 +1,8 @@
 """Block assembly: one residual block per layer, built from config flags.
 
-Families covered (all bidirectional — diffusion LMs score every masked
-position at once, no causal mask exists anywhere):
+Families covered (bidirectional — diffusion LMs score every masked
+position at once — except block-diffusion LMs, whose attention is
+block-causal, ``ModelConfig.attn_block``):
 
 * dense / vlm:      norm → attn → norm → MLP
 * moe:              norm → attn → norm → MoE (shared + routed)

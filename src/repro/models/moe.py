@@ -1,7 +1,22 @@
-"""Mixture-of-Experts: top-k router + capacity-based grouped expert matmuls.
+"""Mixture-of-Experts: top-k router + grouped expert matmuls.
 
-TPU-native design notes
------------------------
+Two dispatch paths share the router:
+
+* **Dropless** (no activation mesh: the path ``Decoder`` and the server
+  run on one device).  The layer holds the experts
+  ``MoEConfig.held_first ..`` (all by default; one chip's share under
+  expert parallelism), routes every token over all ``num_experts``, and
+  computes only the (token, expert) pairs whose expert it holds: the
+  pairs are sorted by expert and each projection is one grouped matmul
+  over the held experts' weights with the groups' sizes (the Pallas
+  megablox ``gmm`` on TPU, ``jax.lax.ragged_dot`` elsewhere).  No token
+  is dropped and no expert is padded.  While a decode runner counts
+  (``expert_counter``), each call adds its computed pairs, the held
+  experts that got a row, and one call.
+* **Capacity** (under an activation mesh, ``launch/dryrun.py``), below.
+
+Capacity-path design notes
+--------------------------
 * Dispatch is **sort + gather** (never scatter): tokens are argsorted by
   expert id, each expert reads a contiguous capacity-C slice, and the combine
   step gathers each token's expert output back by its inverse-permutation
@@ -18,6 +33,8 @@ TPU-native design notes
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from typing import Tuple
 
 import jax
@@ -25,7 +42,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import Params, dense_init, init_mlp, apply_mlp
-from repro.parallel.ctx import constrain
+from repro.parallel.ctx import constrain, option
 
 
 def _round_up(x: int, m: int) -> int:
@@ -48,10 +65,10 @@ def init_moe(rng, cfg: ModelConfig) -> Params:
     ks = jax.random.split(rng, 5)
     p: Params = {
         "router": dense_init(ks[0], (d, m.num_experts), scale=0.02),
-        # stacked expert weights: SwiGLU gate/up/down per expert
-        "w_gate": dense_init(ks[1], (m.num_experts, d, ff)),
-        "w_up": dense_init(ks[2], (m.num_experts, d, ff)),
-        "w_down": dense_init(ks[3], (m.num_experts, ff, d)),
+        # stacked weights of the held experts: SwiGLU gate/up/down
+        "w_gate": dense_init(ks[1], (m.num_held, d, ff)),
+        "w_up": dense_init(ks[2], (m.num_held, d, ff)),
+        "w_down": dense_init(ks[3], (m.num_held, ff, d)),
     }
     if m.num_shared_experts:
         p["shared"] = init_mlp(ks[4], cfg, d_ff=ff * m.num_shared_experts)
@@ -84,7 +101,115 @@ def load_balance_loss(logits: jnp.ndarray, ids: jnp.ndarray,
 
 
 # --------------------------------------------------------------------------
-# forward
+# dropless forward (one device, the held experts)
+# --------------------------------------------------------------------------
+
+_COUNTS: contextvars.ContextVar = contextvars.ContextVar("expert_counts",
+                                                         default=None)
+
+
+@contextlib.contextmanager
+def expert_counter(cfg: ModelConfig):
+    """Count the dropless layer's work while tracing inside the block.
+
+    Yields ``read() -> (3,) int32`` — the (token, held expert) pairs
+    computed, the held experts that got at least one row (summed over
+    calls), and the expert-layer calls — for an MoE ``cfg``, to be
+    called before the block ends, inside the same trace; ``read()`` is
+    ``None`` for other configs, whose programs stay as they were.  The
+    counts live in a ``jax.Ref``, so the layer adds to them from inside
+    the layer scan, the decode's ``while_loop`` and the search's
+    ``lax.cond`` alike."""
+    if not cfg.is_moe:
+        yield lambda: None
+        return
+    ref = jax.new_ref(jnp.zeros((3,), jnp.int32))
+    token = _COUNTS.set(ref)
+    try:
+        yield lambda: ref[...]
+    finally:
+        _COUNTS.reset(token)
+
+
+# the kernel's row tile; each step reads one expert's whole matrix.  At
+# SDAR-30B-A3B's widths on one v5e (48 layers of 16 held experts, three
+# projections) 64 rows took 295 us a layer at 64-1024 buffered pairs and
+# 658 us at 32768, against 346/673 us at 128 rows, 450/753 us at 256,
+# 1277 us at (128, 128, 128) tiles and 785/1162 us for jax.lax.ragged_dot
+GMM_ROWS = 64
+
+
+def _gmm(x: jnp.ndarray, w: jnp.ndarray,
+         group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """The TPU branch: the megablox kernel over ``x`` padded to whole
+    ``GMM_ROWS`` tiles (its differentiable entry point: a train step
+    traces both branches' derivatives)."""
+    from jax.experimental.pallas.ops.tpu.megablox.ops import gmm
+    m = x.shape[0]
+    xp = jnp.pad(x, ((0, -m % GMM_ROWS), (0, 0)))
+    return gmm(xp, w, group_sizes, x.dtype,
+               (GMM_ROWS, w.shape[1], w.shape[2]))[:m]
+
+
+def _ragged_dot(x: jnp.ndarray, w: jnp.ndarray,
+                group_sizes: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.ragged_dot(x, w, group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)
+
+
+def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray,
+                   group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """Rows of ``x`` (M, k), sorted by group, times their group's matrix
+    of ``w`` (G, k, n); rows past ``sum(group_sizes)`` are left undefined
+    (the TPU kernel visits only the tiles that hold a group's rows).  The
+    platform the program is lowered for picks the kernel: megablox
+    ``gmm`` on TPU, ``jax.lax.ragged_dot`` elsewhere."""
+    return jax.lax.platform_dependent(x, w, group_sizes, tpu=_gmm,
+                                      default=_ragged_dot)
+
+
+@jax.named_scope("experts")
+def _dropless(p: Params, tokens: jnp.ndarray, cfg: ModelConfig
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """tokens (T, d) -> (the held experts' part of the output (T, d),
+    aux loss)."""
+    m = cfg.moe
+    t, d = tokens.shape
+    k, held = m.num_experts_per_tok, m.num_held
+    dt = tokens.dtype
+
+    logits = tokens @ p["router"].astype(dt)                    # (T, E)
+    gates, ids = router_topk(logits, k)                         # (T, k)
+    aux = load_balance_loss(logits, ids, m.num_experts) * m.router_aux_coef
+
+    # pairs to experts held elsewhere sort last, under the id ``held``
+    local = ids.reshape(t * k) - m.held_first
+    mine = (local >= 0) & (local < held)
+    local = jnp.where(mine, local, held)
+    order = jnp.argsort(local, stable=True)                     # (Tk,)
+    rank = jnp.argsort(order, stable=True)                      # inverse
+    sizes = jnp.sum(jax.nn.one_hot(local, held, dtype=jnp.int32), axis=0)
+
+    xs = jnp.take(tokens, order // k, axis=0)                   # (Tk, d)
+    h = jax.nn.silu(grouped_matmul(xs, p["w_gate"].astype(dt), sizes)) \
+        * grouped_matmul(xs, p["w_up"].astype(dt), sizes)
+    ys = grouped_matmul(h, p["w_down"].astype(dt), sizes)       # (Tk, d)
+
+    # back to (token, slot) order; pairs not held read zero (their rows
+    # lie past the groups and were never computed)
+    ys = jnp.where(mine[:, None], jnp.take(ys, rank, axis=0), 0)
+    out = jnp.sum(ys.reshape(t, k, d) * gates[..., None].astype(dt),
+                  axis=1)
+    counts = _COUNTS.get()
+    if counts is not None:
+        counts[...] += jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+                                  jnp.int32(1)])
+    return out, aux
+
+
+# --------------------------------------------------------------------------
+# capacity forward (under an activation mesh)
 # --------------------------------------------------------------------------
 
 def _dispatch(p: Params, tokens: jnp.ndarray, cfg: ModelConfig,
@@ -147,7 +272,9 @@ def moe_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x (B, L, d) -> (out (B, L, d), aux_loss scalar).
 
-    Sort-based dispatch: gather-only data movement, batched expert einsums.
+    Without an activation mesh: the dropless layer over the held experts
+    (``capacity_factor`` does not apply).  Under one: sort-based
+    capacity dispatch, gather-only data movement, batched expert einsums.
 
     SHARD-LOCAL dispatch (§Perf iteration A1): the argsort is global over
     all T tokens, which GSPMD can only realize by replicating the token
@@ -162,6 +289,13 @@ def moe_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     m = cfg.moe
     b, l, d = x.shape
     t = b * l
+    if option("mesh") is None:
+        out, aux = _dropless(p, x.reshape(t, d), cfg)
+        out = out.reshape(b, l, d)
+        if m.num_shared_experts:
+            out = out + apply_mlp(p["shared"], x.reshape(t, d),
+                                  cfg).reshape(b, l, d)
+        return out, aux
     gd, gm = shard_counts()
     # expert-parallel-capable configs (E divides the model axis — DeepSeek)
     # keep the GLOBAL dispatch: its all-to-all is the efficient path, and

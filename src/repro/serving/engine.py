@@ -66,7 +66,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import DecodeConfig, ModelConfig
-from repro.core.decoder import Decoder, SampleStats, validate_cache_policy
+from repro.core.decoder import (Decoder, SampleStats, validate_block_causal,
+                                validate_cache_policy)
 from repro.core.strategies import resolve_strategy
 from repro.serving.faults import FaultInjector, validate_block_tokens
 from repro.serving import tracing
@@ -195,6 +196,7 @@ class ServingEngine:
             raise ValueError(
                 f"steps={dcfg.steps} is infeasible: {num_blocks} blocks "
                 f"need at least one step each")
+        validate_block_causal(self.cfg, dcfg, len(prompt))
         rid = self._next_id
         self._next_id += 1
         now = tracing.now()
@@ -471,8 +473,9 @@ class ServingEngine:
             # count: the per-example histogram, which keeps the
             # sum(phase_counts) == steps invariant per request and keeps
             # replica rows from inflating the reported phase work.
-            # revocations / skipped_forwards are whole-batch totals like
-            # forwards: each real request gets its share
+            # revocations / skipped_forwards and the expert counters are
+            # whole-batch totals like forwards: each real request gets its
+            # share
             # the trace (dcfg.trace decodes only) is per-POSITION, not
             # pro-rated: each request gets its own row of the commit
             # maps, pad columns sliced off so commit_step indexes line
@@ -484,6 +487,9 @@ class ServingEngine:
                 wall_time=stats.wall_time / real,
                 revocations=stats.revocations / real,
                 skipped_forwards=stats.skipped_forwards / real,
+                expert_rows=stats.expert_rows / real,
+                experts_read=stats.experts_read / real,
+                expert_calls=stats.expert_calls / real,
                 phase_counts={k: v / rows
                               for k, v in stats.phase_counts.items()},
                 trace=stats.trace.slice_rows(i, batch.pads[i])

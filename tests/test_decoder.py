@@ -339,11 +339,13 @@ def test_lower_blocks_is_the_served_program(model, strategy, policy):
     args = (x, jax.random.PRNGKey(5), jnp.int32(8), jnp.asarray(sched[0]),
             jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32),
             strat.init_carry_shaped(CFG, dcfg, 2, x.shape[1]))
+    # each runner returns its outputs and the expert counters (None for
+    # a model without experts)
     if policy == "none":
-        out = compiled["block"](params, {}, *args)
+        out, _ = compiled["block"](params, {}, *args)
     else:
-        state = compiled["refresh"](params, x)
-        out = compiled["block"](params, *args, state)
+        state, _ = compiled["refresh"](params, x)
+        out, _ = compiled["block"](params, *args, state)
     np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(first.x))
 
     # shapes alone lower the same programs, with nothing on the device

@@ -74,3 +74,24 @@ def test_confidence_kernel_reads_the_cell_logits_in_place(one_chip):
     # a bitcast of the caller's logits (no copy): same bytes, 2-D view
     assert re.search(r"= f32\[1024,126464\]\{[^}]*\} "
                      r"(bitcast|parameter)\(", defn), defn
+
+
+@pytest.mark.parametrize("rows,k,n", [(512, 2048, 768), (512, 768, 2048),
+                                      (32768, 2048, 768), (520, 2048, 768)],
+                         ids=["window-gate", "window-down", "refresh-gate",
+                              "ragged-rows"])
+def test_expert_kernel_compiles_for_v5e(one_chip, rows, k, n):
+    """The dropless expert layer's grouped matmul lowered for the chip
+    (``models.moe.grouped_matmul``, which picks its kernel by the
+    platform it is lowered for): the megablox kernel over SDAR-30B-A3B's
+    16 held experts of 2048 x 768, the whole matrix a step, at the cell's
+    window and refresh buffers, and a buffer padded to whole tiles."""
+    from repro.models.moe import grouped_matmul
+    x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((16, k, n), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(grouped_matmul).lower(x, w, sizes)
+    assert lowered.out_info.shape == (rows, n)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "ragged" not in text
